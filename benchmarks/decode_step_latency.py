@@ -71,7 +71,9 @@ def run(quick: bool = True):
     for impl in ("chunked", "pallas"):
         for stacked in (False, True):
             per_tick, nrows = _fill_and_time(
-                cfg.replace(attn_impl=impl), paths, stacked=stacked,
+                # the smoke preset on the CPU: kernels interpreted
+                cfg.replace(attn_impl=impl, pallas_interpret=True), paths,
+                stacked=stacked,
                 slots=slots, cache_len=cache_len, prompt_len=prompt_len,
                 warm_ticks=3, ticks=ticks)
             label = ("jnp" if impl == "chunked" else "pallas",

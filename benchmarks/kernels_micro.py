@@ -28,7 +28,7 @@ def run(quick: bool = True):
     q = jax.random.normal(ks[0], (1, 256, 4, 64))
     k = jax.random.normal(ks[1], (1, 256, 2, 64))
     v = jax.random.normal(ks[2], (1, 256, 2, 64))
-    us, out = _time(ops.flash_attention, q, k, v, causal=True,
+    us, out = _time(ops.flash_attention_trainable, q, k, v, causal=True,
                     block_q=64, block_k=64, interpret=True)
     err = float(jnp.abs(out - ref.flash_attention_ref(q, k, v)).max())
     rows.append({"name": "kernel_flash_attention_256", "us_per_call": us,

@@ -314,7 +314,14 @@ def _mesh_lane_child(quick: bool):
 def _mesh_lane_rows(quick: bool):
     """Run the mesh lane in a subprocess where XLA can still be told to
     present 8 host devices (the parent's device count is locked at its
-    first jax use)."""
+    first jax use).  It times forced host devices, so a parent that
+    holds an accelerator refuses instead of recording CPU rows."""
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"the mesh lane times 8 forced CPU devices in a child "
+            f"process; this parent holds the {jax.default_backend()!r} "
+            f"backend, whose rows it would not measure")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8"

@@ -11,6 +11,8 @@ import math
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 def _derived(row: dict) -> str:
     skip = {"name", "us_per_call"}
@@ -112,6 +114,7 @@ def main() -> None:
                          "any failure or non-finite metric")
     args = ap.parse_args()
     quick = not args.full
+    enable_compile_cache()
 
     from . import (decode_step_latency, deploy_latency, elastic_fleet,
                    fig8_convergence, fig9_path_scaling, fig11_alternating,
@@ -176,6 +179,10 @@ def main() -> None:
     if args.smoke and failures:
         for f in failures:
             print(f"SMOKE FAILURE: {f}", file=sys.stderr)
+        sys.exit(1)
+    raised = [n for n, rows in results.items() if rows is None]
+    if raised:
+        print(f"suite(s) raised: {', '.join(raised)}", file=sys.stderr)
         sys.exit(1)
 
 

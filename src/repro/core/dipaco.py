@@ -27,6 +27,7 @@ from repro.data.sharder import PreShardedDataset
 from repro.models import api
 from repro.models.config import DiPaCoConfig, ModelConfig
 from repro.models.lm import apply_lm, lm_loss
+from repro.models.params import cast_tree
 from repro.optim import adamw_init, cosine_schedule
 from repro.core.diloco import outer_state_init, outer_step
 from repro.core.partition import make_partition, mixing_matrices
@@ -36,6 +37,16 @@ from repro.launch.steps import make_inner_train_step
 def stack_tree(tree, n):
     return jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x[None], (n, *x.shape)).copy(), tree)
+
+
+def master_rows(tree, n):
+    """``n`` stacked float32 copies of ``tree``: the workers' master
+    weights, and the global copy they sync against.  The model computes
+    in ``cfg.dtype`` (every layer casts its weights to the activations'
+    dtype); AdamW updates the f32 masters, where a bf16 copy would round
+    away every update below half a bf16 step (2^-9 at the norm scales'
+    1.0, more than a step of the default schedule)."""
+    return stack_tree(cast_tree(tree, jnp.float32), n)
 
 
 def row(tree, i):
@@ -95,10 +106,8 @@ class DiPaCoTrainer:
         else:
             _, axes = api.init_model(key, cfg)
         self.axes = axes
-        self.worker_params = stack_tree(base_params, W)
-        self.global_params = stack_tree(
-            jax.tree_util.tree_map(
-                lambda x: x.astype(jnp.float32), base_params), W)
+        self.worker_params = master_rows(base_params, W)
+        self.global_params = master_rows(base_params, W)
         self.opt_state = jax.vmap(adamw_init)(self.worker_params)
         self.outer_state = outer_state_init(self.global_params)
         alphas = dataset.alphas() if dcfg.loss_reweigh else None
@@ -114,8 +123,12 @@ class DiPaCoTrainer:
         self.lr = lambda t: cosine_schedule(
             t, peak_lr=peak_lr, warmup=warmup, total_steps=total_steps)
         self._inner = make_inner_train_step(cfg)
-        self._phase_fn = jax.jit(self._make_phase())
-        self._outer_fn = jax.jit(self._make_outer())
+        # phase and outer step update the stacked state in place: four
+        # full-width workers' params, AdamW moments, global copies and
+        # outer momenta only fit one chip without a second copy
+        # (run_phase rebinds every donated reference)
+        self._phase_fn = jax.jit(self._make_phase(), donate_argnums=(0, 1))
+        self._outer_fn = jax.jit(self._make_outer(), donate_argnums=(0, 1, 2))
 
         @jax.jit
         def _nll_eval(p, tk):
@@ -169,15 +182,20 @@ class DiPaCoTrainer:
         return outer
 
     # ------------------------------------------------------------------
-    def run_phase(self, tau: Optional[int] = None) -> PhaseMetrics:
+    def phase_inputs(self, tau: Optional[int] = None):
+        """The next phase's ``(batches (tau, W, B, S), lrs (tau,))``."""
         from repro.data.loader import phase_batches
         tau = tau or self.dcfg.inner_steps
         batches = np.stack(
             [phase_batches(ld.tokens, ld.batch_size, tau, i, self.phase)
              for i, ld in enumerate(self.loaders)], axis=1)
         lrs = jnp.asarray([self.lr(self.step + t) for t in range(tau)])
+        return jnp.asarray(batches), lrs
+
+    def run_phase(self, tau: Optional[int] = None) -> PhaseMetrics:
+        tau = tau or self.dcfg.inner_steps
         self.worker_params, self.opt_state, losses = self._phase_fn(
-            self.worker_params, self.opt_state, jnp.asarray(batches), lrs)
+            self.worker_params, self.opt_state, *self.phase_inputs(tau))
         self.step += tau
         self.phase += 1
         self.worker_params, self.global_params, self.outer_state = \

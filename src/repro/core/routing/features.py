@@ -15,12 +15,14 @@ def prefix_features(params, cfg: ModelConfig, tokens, prefix_len=None,
     """tokens: (N, S) -> (N, d_model) float32 features."""
     pl = prefix_len or cfg.route_prefix_len
 
+    # params are an argument, not a closure: closed-over arrays would be
+    # baked into the executable as constants (the whole model's weights)
     @jax.jit
-    def feat(tk):
-        hidden, _ = apply_lm(params, cfg, tk[:, :pl], return_hidden=True)
+    def feat(p, tk):
+        hidden, _ = apply_lm(p, cfg, tk[:, :pl], return_hidden=True)
         return jnp.mean(hidden.astype(jnp.float32), axis=1)
 
     outs = []
     for i in range(0, tokens.shape[0], batch_size):
-        outs.append(feat(tokens[i:i + batch_size]))
+        outs.append(feat(params, tokens[i:i + batch_size]))
     return jnp.concatenate(outs, axis=0)

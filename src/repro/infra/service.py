@@ -50,6 +50,7 @@ from repro.data.loader import ShardLoader, phase_batches
 from repro.data.sharder import PreShardedDataset
 from repro.models import api
 from repro.models.config import DiPaCoConfig, ModelConfig
+from repro.models.params import cast_tree
 from repro.optim import adamw_init, adamw_update, cosine_schedule
 from repro.core.dipaco import PhaseMetrics
 from repro.obs import MetricRegistry, as_telemetry
@@ -99,7 +100,10 @@ class TrainingService:
         else:
             _, axes = api.init_model(key, cfg)
         self.axes = axes
-        self.store = ModuleStore(base_params, axes, self.partition)
+        # f32 masters, as in the vectorized trainer (core/dipaco.py
+        # master_rows): workers assemble and train f32 copies
+        self.store = ModuleStore(cast_tree(base_params, jnp.float32), axes,
+                                 self.partition)
         alphas = dataset.alphas() if dcfg.loss_reweigh else \
             np.ones(W) / W
         if ckpt_retention is None:

@@ -19,10 +19,11 @@ dense ``(B, H, S, T)`` masked-einsum cache branch of
   scales are dequantized in VMEM right before the dot, so the quantized
   cache never round-trips through an f32 HBM materialization.
 
-Target: TPU v5e.  VMEM working set per grid step is one q group
-``(G, D)`` plus one K and one V block ``(block_k, D)`` (int8 or f32)
-plus scratch — comfortably under budget for ``D <= 256``.  On CPU CI
-the kernel runs in interpret mode (see ``ops.decode_attention``).
+Target: TPU v5e.  VMEM working set per grid step is the row's queries
+``(H, D)`` plus one K and one V block ``(KH, D, block_k)`` (int8 or
+bf16) plus scratch — 0.5 MiB for 16x64 heads at ``block_k=128``.  Off the
+TPU the kernel runs only in interpret mode, which the caller asks for
+(see ``ops.decode_attention``).
 """
 from __future__ import annotations
 
@@ -38,15 +39,35 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _block_has_valid(j, ci, *, T: int, block_k: int,
+                     window: Optional[int]):
+    """Scalar test: does key block ``j`` hold any admissible ring slot?
+
+    The admissible entries form a ring interval of ``n`` slots ending at
+    slot ``ci % T`` (the newest write), ``n = min(ci + 1, window, T)``.
+    Pure scalar arithmetic, so the skip needs no vector reduction."""
+    n = ci + 1
+    if window is not None:
+        n = jnp.minimum(n, window)
+    last = ci % T
+    first = last - n + 1                      # may be negative: wraps
+    lo = j * block_k
+    hi = lo + block_k - 1
+    plain = jnp.logical_and(lo <= last, hi >= first)
+    wrapped = jnp.logical_or(lo <= last, hi >= first + T)
+    return jnp.logical_or(n >= T, jnp.where(first >= 0, plain, wrapped))
+
+
 def _decode_kernel(ci_ref, q_ref, k_ref, v_ref, *rest,
                    quantized: bool, T: int, block_k: int, nk: int,
-                   window: Optional[int], scale: float):
+                   kh: int, g: int, d: int, window: Optional[int],
+                   scale: float):
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     bi = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -54,50 +75,60 @@ def _decode_kernel(ci_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # ring-slot validity, reconstructed from this row's decode position:
-    # the token at position ci sits in slot ci % T; slots "after" it in
-    # ring order hold entries T positions older (or nothing yet).
     ci = ci_ref[bi]
-    slot = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k,), 0)
-    idx_last = ci % T
-    abs_pos = jnp.where(slot <= idx_last, ci - idx_last + slot,
-                        ci - idx_last - T + slot)
-    valid = (abs_pos >= 0) & (abs_pos <= ci)
-    if window is not None:
-        valid &= abs_pos > ci - window
 
-    @pl.when(jnp.any(valid))
+    @pl.when(_block_has_valid(j, ci, T=T, block_k=block_k, window=window))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)              # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)        # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid[None, :], s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        # ring-slot validity, reconstructed from this row's decode
+        # position: the token at position ci sits in slot ci % T; slots
+        # "after" it in ring order hold entries T positions older (or
+        # nothing yet).  Slots run along lanes, as a (1, block_k) row.
+        slot = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        idx_last = ci % T
+        abs_pos = jnp.where(slot <= idx_last, ci - idx_last + slot,
+                            ci - idx_last - T + slot)
+        valid = jnp.logical_and(abs_pos >= 0, abs_pos <= ci)
+        if window is not None:
+            valid = jnp.logical_and(valid, abs_pos > ci - window)
+        # one KV head at a time: its (D, bk) slab of keys and values,
+        # tokens along lanes, against its g query rows (the heads of one
+        # KV group are contiguous)
+        for h in range(kh):
+            rows = slice(h * g, (h + 1) * g)
+            q = q_ref[0, rows, :].astype(jnp.float32)        # (g, D)
+            k = k_ref[0, h].astype(jnp.float32)              # (D, bk)
+            v = v_ref[0, h].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if quantized:
+                # per-(slot, head) scales factor out of the dots: one
+                # (1, bk) row each, on the scores and the probabilities
+                s = s * ks_ref[0, h:h + 1, :]
+            s = jnp.where(valid, s * scale, NEG_INF)
+            m_prev = m_scr[rows, :]                          # (g, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[rows, :] = l_scr[rows, :] * alpha + p.sum(axis=-1,
+                                                             keepdims=True)
+            if quantized:
+                p = p * vs_ref[0, h:h + 1, :]
+            acc_scr[rows, :] = acc_scr[rows, :] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[rows, :] = m_new
 
     @pl.when(j == nk - 1)
     def _write():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def _pick_block_k(T: int, block_k: int) -> int:
-    bk = min(block_k, T)
-    while T % bk:
-        bk //= 2
-    return max(bk, 1)
+    """``block_k`` when it tiles the cache, else the whole cache length
+    (a block equal to the array dimension is always a legal TPU tile)."""
+    return block_k if T % block_k == 0 else T
 
 
 def flash_decode(q, k_cache, v_cache, cache_index, *,
@@ -112,49 +143,50 @@ def flash_decode(q, k_cache, v_cache, cache_index, *,
     the current token was just written at; masking admits ring entries
     with absolute position in ``[max(0, ci-window+1), ci]``).
 
+    The kernel reads the caches in place.  On a TPU a ``(B, T, KH, D)``
+    cache with D < 128 lies in memory as ``(B, KH, D, T)``, tokens along
+    lanes (its default layout), so the kernel takes that view, which XLA
+    makes a bitcast, and the scales as ``(B, KH, T)`` likewise.  Each
+    grid step's block is ``(KH, D, block_k)``: minor dimensions the
+    tiling rule accepts for D a multiple of 8, and one DMA for all heads.
+
     Returns (B, H, D) in q's dtype.
     """
     b, h, d = q.shape
     T, kh = k_cache.shape[1], k_cache.shape[2]
     assert h % kh == 0, (h, kh)
-    g = h // kh
     quantized = k_scale is not None
     assert quantized == (v_scale is not None)
     bk = _pick_block_k(T, block_k)
     nk = T // bk
-    qg = q.reshape(b, kh, g, d)
     ci = jnp.asarray(cache_index, jnp.int32).reshape(b)
     kernel = functools.partial(
         _decode_kernel, quantized=quantized, T=T, block_k=bk, nk=nk,
-        window=window, scale=1.0 / math.sqrt(d))
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda bi, hi, j, ci: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, bk, 1, d), lambda bi, hi, j, ci: (bi, j, hi, 0)),
-        pl.BlockSpec((1, bk, 1, d), lambda bi, hi, j, ci: (bi, j, hi, 0)),
-    ]
-    args = [qg, k_cache, v_cache]
+        kh=kh, g=h // kh, d=d, window=window, scale=1.0 / math.sqrt(d))
+    q_spec = pl.BlockSpec((1, h, d), lambda bi, j, ci: (bi, 0, 0))
+    kv_spec = pl.BlockSpec((1, kh, d, bk), lambda bi, j, ci: (bi, 0, 0, j))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    args = [q] + [jnp.transpose(c, (0, 2, 3, 1)) for c in (k_cache, v_cache)]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bk, 1), lambda bi, hi, j, ci: (bi, j, hi)),
-            pl.BlockSpec((1, bk, 1), lambda bi, hi, j, ci: (bi, j, hi)),
-        ]
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        sc_spec = pl.BlockSpec((1, kh, bk), lambda bi, j, ci: (bi, 0, j))
+        in_specs += [sc_spec, sc_spec]
+        args += [jnp.swapaxes(x.astype(jnp.float32), 1, 2)
+                 for x in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kh, nk),
+        grid=(b, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bi, hi, j, ci: (bi, hi, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kh, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
+        name="flash_decode",
     )(ci, *args)
-    return out.reshape(b, h, d)

@@ -1,46 +1,32 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (correctness
-path); on a real TPU set ``interpret=False`` (the default resolves by
-backend).  The model layer picks these up when ``cfg.attn_impl ==
-'pallas'`` etc.
+The kernels compile for the TPU.  Interpret mode (the correctness path
+on a CPU) runs only when the caller passes ``interpret=True``; it is
+never inferred from the backend, so a TPU run that lost its chip fails
+(Pallas refuses a compiled kernel on the CPU) instead of silently
+interpreting.  The model layer picks these up when ``cfg.attn_impl ==
+'pallas'``, with ``interpret=cfg.pallas_interpret``.
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from .decode_attention import flash_decode as _flash_decode
-from .flash_attention import flash_attention as _flash
 from .moe_gmm import expert_gemm as _gemm
 from .router_assign import router_assign as _assign
 from .ssd_scan import ssd_scan as _ssd
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k", "interpret"))
-def flash_attention(q, k, v, *, causal=True, window=None, block_q=128,
-                    block_k=128, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
-                  block_k=block_k, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_k",
                                              "interpret"))
 def decode_attention(q, k_cache, v_cache, cache_index, *, window=None,
                      k_scale=None, v_scale=None, block_k=128,
-                     interpret=None):
+                     interpret=False):
     """Flash-decode: single-token GQA attention over the ring KV cache
     (split-K online softmax, in-kernel ring/window masking, fused int8
     dequant).  q: (B, H, D); caches (B, T, KH, D); cache_index (B,)."""
-    interpret = _default_interpret() if interpret is None else interpret
     return _flash_decode(q, k_cache, v_cache, cache_index, window=window,
                          k_scale=k_scale, v_scale=v_scale, block_k=block_k,
                          interpret=interpret)
@@ -49,30 +35,29 @@ def decode_attention(q, k_cache, v_cache, cache_index, *, window=None,
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention_trainable(q, k, v, *, causal=True, window=None,
-                              block_q=128, block_k=128, interpret=None):
-    """Differentiable flash attention (custom_vjp with the Pallas
-    backward kernels — dq/dkv with blockwise p recomputation)."""
+                              block_q=128, block_k=128, interpret=False):
+    """Flash attention, q: (B, S, H, D); k, v: (B, S, KH, D).  The one
+    entry for inference and training: undifferentiated it runs the
+    forward kernel alone; under ``jax.grad`` its custom_vjp saves the
+    log-sum-exp and runs the Pallas backward kernels (dq/dkv with
+    blockwise p recomputation)."""
     from .flash_attention_bwd import flash_attention_trainable as _fat
-    interpret = _default_interpret() if interpret is None else interpret
     return _fat(q, k, v, causal, window, block_q, block_k, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def router_assign(z, centroids, *, block_n=256, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+def router_assign(z, centroids, *, block_n=256, interpret=False):
     return _assign(z, centroids, block_n=block_n, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, a, bmat, cmat, *, chunk=128, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+def ssd_scan(x, dt, a, bmat, cmat, *, chunk=128, interpret=False):
     return _ssd(x, dt, a, bmat, cmat, chunk=chunk, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
                                              "interpret"))
 def expert_gemm(xe, w, *, block_m=128, block_n=128, block_k=512,
-                interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+                interpret=False):
     return _gemm(xe, w, block_m=block_m, block_n=block_n, block_k=block_k,
                  interpret=interpret)

@@ -8,6 +8,10 @@
     PYTHONPATH=src python -m repro.launch.serve --engine continuous \
         --rate 40
 
+    # the same on a CPU: the reduced preset, Pallas kernels interpreted
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+        --smoke --engine continuous
+
     # serve the promoted version of a deployment registry (written by
     # examples/train_and_serve.py or a Publisher), hot-swapping when
     # the serving pointer moves
@@ -25,10 +29,13 @@ from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Any, List, Optional
 
 import jax
 
-from repro.configs import get_smoke_config
+from repro.compile_cache import enable_compile_cache
+from repro.configs import get_config, get_smoke_config
 from repro.data import SyntheticCorpus
 from repro.models import api
 from repro.models.config import DiPaCoConfig
@@ -37,9 +44,19 @@ from repro.serving import (ContinuousBatchingEngine, EngineOptions,
                            prefix_hash_router)
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dipaco-150m")
+    ap.add_argument("--smoke", action="store_true",
+                    help="the arch's reduced preset (CPU rehearsals); "
+                         "Pallas kernels then run in interpret mode")
+    ap.add_argument("--attn-impl", choices=["chunked", "pallas"],
+                    default="chunked",
+                    help="attention: XLA online-softmax, or the Pallas "
+                         "flash kernels (flash_decode on every tick)")
+    ap.add_argument("--cache-len", type=int, default=0,
+                    help="KV cache length per slot (default: prompt-len "
+                         "+ max-new)")
     ap.add_argument("--engine", choices=["oneshot", "continuous"],
                     default="oneshot")
     ap.add_argument("--continuous", action="store_true",
@@ -75,16 +92,44 @@ def main() -> None:
                     default="process",
                     help="fleet members as OS processes (default) or "
                          "in this process (debugging)")
-    args = ap.parse_args()
-    engine_kind = "continuous" if args.continuous else args.engine
+    return ap
 
-    cfg = get_smoke_config(args.arch).replace(route_prefix_len=8)
+
+@dataclass
+class Setup:
+    """What every engine kind serves from."""
+    cfg: Any
+    corpus: SyntheticCorpus
+    registry: Any                  # DeploymentRegistry, or None
+    paths: Optional[list]          # random paths; None with a registry
+    opts: EngineOptions
+
+
+@dataclass
+class ContinuousRun:
+    """What one ``--engine continuous`` run served."""
+    engine: ContinuousBatchingEngine
+    trace: list
+    finished: List[Any]
+    compile_s: float               # engine.warmup(): every jit entry
+    serve_s: float                 # the trace, on the wall clock
+
+
+def build_config(args):
+    """The served model: the arch's ``config()``, or with ``--smoke``
+    its reduced preset with the Pallas kernels interpreted."""
+    if args.smoke:
+        cfg = get_smoke_config(args.arch).replace(pallas_interpret=True)
+    else:
+        cfg = get_config(args.arch)
+    return cfg.replace(route_prefix_len=8, attn_impl=args.attn_impl)
+
+
+def setup(args) -> Setup:
+    cfg = build_config(args)
     corpus = SyntheticCorpus(vocab_size=cfg.vocab_size, num_domains=4,
                              seq_len=args.prompt_len, seed=0)
-    prompts = corpus.sample_documents(args.requests)
-    cache_len = args.prompt_len + args.max_new
-
-    registry = None
+    registry = paths = None
     if args.deploy_root:
         from repro.deploy import DeploymentRegistry
         levels = tuple(int(x) for x in args.levels.split("x"))
@@ -94,28 +139,59 @@ def main() -> None:
         num_paths = registry.num_paths
         print(f"[serve] registry {args.deploy_root}: versions "
               f"{registry.versions}, serving v{registry.serving_version}")
-        paths = None
     else:
         key = jax.random.PRNGKey(args.seed)
         num_paths = args.paths
         paths = [api.init_model(jax.random.fold_in(key, p), cfg)[0]
                  for p in range(num_paths)]
+    # one validated options bag configures either engine; every prompt
+    # has --prompt-len tokens, so that is the one prefill bucket besides
+    # cache_len (which the engine always adds)
+    opts = EngineOptions(
+        registry=registry, swap_policy=args.swap_policy,
+        cache_len=args.cache_len or args.prompt_len + args.max_new,
+        slots_per_path=args.slots, reroute_every=args.reroute_every,
+        route_fn=prefix_hash_router(num_paths),
+        prefill_buckets=(args.prompt_len,))
+    return Setup(cfg, corpus, registry, paths, opts)
 
-    # one validated options bag configures either engine
-    opts = EngineOptions(registry=registry, swap_policy=args.swap_policy,
-                         cache_len=cache_len, slots_per_path=args.slots,
-                         reroute_every=args.reroute_every,
-                         route_fn=prefix_hash_router(num_paths))
+
+def trace_of(args, st: Setup):
+    return poisson_trace(args.requests, rate=args.rate,
+                         prompt_lens=[args.prompt_len],
+                         max_new=args.max_new,
+                         vocab_size=st.cfg.vocab_size, seed=0,
+                         corpus=st.corpus)
+
+
+def run_continuous(args, st: Setup) -> ContinuousRun:
+    """Warm every jit entry off the clock, then serve the Poisson trace
+    on the wall clock."""
+    engine = ContinuousBatchingEngine(st.cfg, st.paths, options=st.opts)
+    t0 = time.perf_counter()
+    engine.warmup()
+    compile_s = time.perf_counter() - t0
+    trace = trace_of(args, st)
+    t0 = time.perf_counter()
+    fins = engine.serve_trace(trace, realtime=True)
+    return ContinuousRun(engine=engine, trace=trace, finished=fins,
+                         compile_s=compile_s,
+                         serve_s=time.perf_counter() - t0)
+
+
+def main(argv=None) -> None:
+    enable_compile_cache()
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    engine_kind = "continuous" if args.continuous else args.engine
+    if args.fleet and not args.deploy_root:
+        ap.error("--fleet requires --deploy-root (fleet members "
+                 "rendezvous on the registry's SERVING pointer)")
+    st = setup(args)
+    cfg, registry, opts = st.cfg, st.registry, st.opts
     if args.fleet:
-        if registry is None:
-            ap.error("--fleet requires --deploy-root (fleet members "
-                     "rendezvous on the registry's SERVING pointer)")
         from repro.serving import ServingFleet
-        trace = poisson_trace(args.requests, rate=args.rate,
-                              prompt_lens=[args.prompt_len],
-                              max_new=args.max_new,
-                              vocab_size=cfg.vocab_size, seed=0,
-                              corpus=corpus)
+        trace = trace_of(args, st)
         t0 = time.time()
         with ServingFleet(cfg, size=args.fleet, options=opts,
                           backend=args.fleet_backend,
@@ -136,16 +212,10 @@ def main() -> None:
               f"{[f.path for f in fins]}")
         return
     if engine_kind == "continuous":
-        engine = ContinuousBatchingEngine(cfg, paths, options=opts)
-        trace = poisson_trace(args.requests, rate=args.rate,
-                              prompt_lens=[args.prompt_len],
-                              max_new=args.max_new,
-                              vocab_size=cfg.vocab_size, seed=0,
-                              corpus=corpus)
-        t0 = time.time()
-        fins = engine.serve_trace(trace, realtime=True)
-        dt = time.time() - t0
+        run = run_continuous(args, st)
+        engine, fins, dt = run.engine, run.finished, run.serve_s
         toks = args.requests * args.max_new
+        print(f"[serve] compiled every jit entry in {run.compile_s:.2f}s")
         lat = sorted(f.latency for f in fins)
         ttft = sorted(f.ttft for f in fins)
         print(f"[serve] {toks} tokens in {dt:.2f}s ({toks / dt:.1f} tok/s) "
@@ -160,10 +230,11 @@ def main() -> None:
         print(f"[serve] request->path: "
               f"{[f.path for f in sorted(fins, key=lambda f: f.rid)]}")
         return
-    engine = PathServingEngine(cfg, paths, options=EngineOptions(
-        registry=registry, cache_len=cache_len))
+    engine = PathServingEngine(cfg, st.paths, options=EngineOptions(
+        registry=registry, cache_len=opts.cache_len))
     t0 = time.time()
-    res = engine.generate(prompts, max_new=args.max_new,
+    res = engine.generate(st.corpus.sample_documents(args.requests),
+                          max_new=args.max_new,
                           reroute_every=args.reroute_every)
     dt = time.time() - t0
     toks = args.requests * args.max_new

@@ -45,10 +45,19 @@ def rules_for(cfg: ModelConfig) -> dict:
     return r
 
 
+def _auto(mesh):
+    """``mesh`` with every axis Auto: the dry-run's layouts are input
+    shardings the partitioner propagates, as the model code expects
+    (``jax.make_mesh`` now makes Explicit axes by default)."""
+    from jax.sharding import AxisType, Mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 def sds(shape, dtype, mesh, axes, rules=None):
     spec = spec_for(tuple(axes), tuple(shape), mesh, rules or RULES)
     return jax.ShapeDtypeStruct(tuple(shape), dtype,
-                                sharding=NamedSharding(mesh, spec))
+                                sharding=NamedSharding(_auto(mesh), spec))
 
 
 def tree_sds(shapes, axes, mesh, prepend=(), rules=None):

@@ -156,7 +156,6 @@ def make_fragment_reduce_step(mesh, ax_list):
     mixed with the full einsum each device evaluates identically, and
     sliced back to the local rows.  ``ax_list`` is the flatten-order
     logical-axes list (core.diloco.leaf_axes_list)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
     from repro.core.diloco import mix_leaf
     from repro.launch.mesh import worker_axes
@@ -183,9 +182,9 @@ def make_fragment_reduce_step(mesh, ax_list):
 
         return {i: one(i, x) for i, x in wire_f.items()}
 
-    fn = shard_map(_local, mesh=mesh,
-                   in_specs=(wspec, PartitionSpec(), PartitionSpec()),
-                   out_specs=wspec, check_rep=False)
+    fn = jax.shard_map(_local, mesh=mesh,
+                       in_specs=(wspec, PartitionSpec(), PartitionSpec()),
+                       out_specs=wspec, check_vma=False)
     return jax.jit(fn)
 
 

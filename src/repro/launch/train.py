@@ -3,10 +3,9 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-8b \
         --levels 4x4 --phases 2 --tau 20 [--smoke] [--backend mesh]
 
-On a TPU fleet this launches the stacked-worker DiPaCo train step on
-``make_production_mesh()``; on this CPU container ``--smoke`` (default
-when only one device is present) uses the reduced config and a debug
-mesh so the same code path runs end to end.
+The model is the arch's ``config()``; ``--smoke`` picks its reduced
+preset instead, for CPU rehearsals (``JAX_PLATFORMS=cpu``) of the same
+code path.  The preset is never chosen from the backend.
 
 ``MeshStreamingTrainer`` is the ``backend="mesh"`` implementation of
 the ``repro.make_trainer`` protocol: DiPaCoTrainer semantics with the
@@ -26,8 +25,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config
-from repro.core.dipaco import PhaseMetrics, row, stack_tree
+from repro.core.dipaco import PhaseMetrics, master_rows, row
 from repro.core.diloco import fragment_state_init
 from repro.core.fragments import FragmentSpec, segment_bounds
 from repro.core.partition import make_partition, mixing_matrices
@@ -87,10 +87,8 @@ class MeshStreamingTrainer:
         def put(tree):
             return jax.device_put(tree, self._wshard)
 
-        self.worker_params = put(stack_tree(base_params, W))
-        self.global_params = put(stack_tree(
-            jax.tree_util.tree_map(
-                lambda x: x.astype(jnp.float32), base_params), W))
+        self.worker_params = put(master_rows(base_params, W))
+        self.global_params = put(master_rows(base_params, W))
         self.opt_state = jax.vmap(adamw_init)(self.worker_params)
         self.fragspec = FragmentSpec(self.global_params,
                                      dcfg.outer_fragments)
@@ -235,7 +233,7 @@ def _parse_profiles(specs):
     return profiles
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dipaco-150m")
     ap.add_argument("--levels", default="2x2")
@@ -244,7 +242,8 @@ def main() -> None:
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--docs", type=int, default=512)
-    ap.add_argument("--smoke", action="store_true", default=None)
+    ap.add_argument("--smoke", action="store_true",
+                    help="the arch's reduced preset (CPU rehearsals)")
     ap.add_argument("--backend", default="vector",
                     choices=("vector", "mesh", "service", "barrier"),
                     help="trainer backend (repro.make_trainer); 'mesh' "
@@ -290,16 +289,20 @@ def main() -> None:
                          "phase (ChaosController)")
     ap.add_argument("--chaos-phase", type=int, default=1,
                     help="phase at which --chaos-kill-frac fires")
-    args = ap.parse_args()
+    return ap
 
-    smoke = args.smoke
-    if smoke is None:
-        smoke = jax.default_backend() != "tpu"
-    cfg = (get_smoke_config(args.arch) if smoke
-           else get_config(args.arch)).replace(route_prefix_len=8)
+
+def build_trainer(args, *, cfg_overrides=None, **trainer_kw):
+    """Routed shards and a trainer for ``args`` (the launcher's code
+    path up to the first phase).  ``cfg_overrides`` replace ModelConfig
+    fields (e.g. a depth cut); ``trainer_kw`` go to the backend (e.g.
+    ``mesh=``)."""
+    cfg = (get_smoke_config(args.arch) if args.smoke
+           else get_config(args.arch)).replace(route_prefix_len=8,
+                                               **(cfg_overrides or {}))
     levels = tuple(int(x) for x in args.levels.split("x"))
     P = int(np.prod(levels))
-    print(f"[launch] arch={cfg.name} smoke={smoke} levels={levels} "
+    print(f"[launch] arch={cfg.name} smoke={args.smoke} levels={levels} "
           f"paths={P} devices={len(jax.devices())}")
 
     corpus = SyntheticCorpus(vocab_size=cfg.vocab_size,
@@ -339,11 +342,18 @@ def main() -> None:
             kw["max_phase_lag"] = args.max_phase_lag
     if args.backend == "vector":
         ckpt_root = None
-    tr = make_trainer(cfg, dcfg, ds, backend=args.backend, key=key,
-                      ckpt_root=ckpt_root, base_params=base,
-                      batch_size=args.batch_size, peak_lr=2e-3,
-                      warmup=args.tau,
-                      total_steps=args.phases * args.tau, **kw)
+    kw.update(trainer_kw)
+    return make_trainer(cfg, dcfg, ds, backend=args.backend, key=key,
+                        ckpt_root=ckpt_root, base_params=base,
+                        batch_size=args.batch_size, peak_lr=2e-3,
+                        warmup=args.tau,
+                        total_steps=args.phases * args.tau, **kw)
+
+
+def main(argv=None) -> None:
+    enable_compile_cache()
+    args = build_parser().parse_args(argv)
+    tr = build_trainer(args)
     t0 = time.time()
     if args.backend == "service" and args.chaos_kill_frac > 0:
         # scripted elasticity demo: kill a fleet fraction mid-phase,

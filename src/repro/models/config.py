@@ -84,6 +84,7 @@ class ModelConfig:
     cross_kv_cache: bool = False   # enc-dec decode: precompute cross K/V
     kv_quant: bool = False         # int8 KV cache (per-token-head scales)
     attn_impl: str = "chunked"     # "chunked" (online-softmax XLA) | "full" | "pallas"
+    pallas_interpret: bool = False  # run "pallas" kernels in interpret mode (CPU)
     attn_chunk_q: int = 512
     attn_chunk_k: int = 512
     causal_skip: bool = False      # structurally skip fully-masked causal chunks

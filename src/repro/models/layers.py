@@ -12,11 +12,22 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from . import params as P
 from .config import ModelConfig
 
 NEG_INF = -1e30
+
+
+def _layout_of(x):
+    """``x``'s sharding under explicit mesh axes, else None.
+
+    With explicit axes, an op whose contraction (or gather) runs over a
+    sharded dimension has no unambiguous output sharding and must be
+    told one; the block's activations keep their input layout."""
+    sh = jax.typeof(x).sharding
+    return None if sh.mesh.empty else sh
 
 
 # ---------------------------------------------------------------------------
@@ -284,7 +295,8 @@ def apply_attention(p, cfg: ModelConfig, x, *, positions, causal=True,
             out = _pallas_decode(
                 q[:, 0], ck, cv, ci, window=window,
                 k_scale=new_cache.get("k_scale"),
-                v_scale=new_cache.get("v_scale"))
+                v_scale=new_cache.get("v_scale"),
+                interpret=cfg.pallas_interpret)
             out = out[:, None].astype(x.dtype)              # (B, 1, H, D)
         else:
             if "k_scale" in new_cache:
@@ -321,18 +333,10 @@ def apply_attention(p, cfg: ModelConfig, x, *, positions, causal=True,
     else:
         causal_eff = causal and not cross
         if cfg.attn_impl == "pallas" and causal_eff:
-            from repro.kernels.ops import flash_attention as _pallas_flash
-            blk = 128
-            pad = (-s) % blk
-            if pad:
-                qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            else:
-                qp, kp, vp = q, k, v
-            # padded keys are in the causal future of all real queries
-            out = _pallas_flash(qp, kp, vp, causal=True, window=window,
-                                block_q=blk, block_k=blk)[:, :s]
+            from repro.kernels.ops import flash_attention_trainable
+            out = flash_attention_trainable(q, k, v, causal=True,
+                                            window=window,
+                                            interpret=cfg.pallas_interpret)
         elif cfg.attn_impl == "full" or cross or s <= cfg.attn_chunk_q:
             out = full_attention(q, k, v, causal=causal_eff, window=window)
         else:
@@ -340,7 +344,8 @@ def apply_attention(p, cfg: ModelConfig, x, *, positions, causal=True,
                 q, k, v, causal=causal_eff, window=window,
                 chunk_q=cfg.attn_chunk_q, chunk_k=cfg.attn_chunk_k,
                 causal_skip=cfg.causal_skip)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype),
+                   out_sharding=_layout_of(x))
     return y, new_cache
 
 
@@ -376,7 +381,8 @@ def apply_mlp(p, cfg: ModelConfig, x):
         h = jax.nn.gelu(x @ p["w_up"].astype(x.dtype))
     else:
         raise ValueError(f"unknown mlp_type {t}")
-    return h @ p["w_down"].astype(x.dtype)
+    return jnp.matmul(h, p["w_down"].astype(x.dtype),
+                      out_sharding=_layout_of(x))
 
 
 # ---------------------------------------------------------------------------
@@ -394,8 +400,14 @@ def init_embedding(key, cfg: ModelConfig):
 
 
 def embed_tokens(p, cfg: ModelConfig, tokens):
-    x = jnp.take(p["embedding"], tokens, axis=0).astype(
-        jnp.dtype(cfg.dtype))
+    # the gathered rows follow the tokens' layout (see _layout_of)
+    sh = _layout_of(tokens)
+    if sh is None:
+        x = jnp.take(p["embedding"], tokens, axis=0)
+    else:
+        x = p["embedding"].at[tokens].get(out_sharding=NamedSharding(
+            sh.mesh, PartitionSpec(*sh.spec, None)))
+    x = x.astype(jnp.dtype(cfg.dtype))
     if cfg.embed_scale:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
     return x

@@ -255,7 +255,11 @@ def lm_loss(logits, tokens, prefix_len: int = 0):
     targets = tokens[:, 1:]
     lg = logits[:, :-1].astype(jnp.float32)
     logz = jax.nn.logsumexp(lg, axis=-1)
-    ll = jnp.take_along_axis(lg, targets[..., None], axis=-1)[..., 0] - logz
+    # a masked sum, not a gather: it also lowers when the vocab axis is
+    # sharded under explicit mesh axes (exact: one term is non-zero)
+    vocab = jax.lax.broadcasted_iota(jnp.int32, lg.shape, lg.ndim - 1)
+    ll = jnp.sum(jnp.where(vocab == targets[..., None], lg, 0.0),
+                 axis=-1) - logz
     pos = jnp.arange(targets.shape[1])[None, :]
     mask = jnp.broadcast_to((pos + 1 >= prefix_len),
                             targets.shape).astype(jnp.float32)

@@ -29,6 +29,7 @@ Two backends share the front-door logic:
 * ``backend="process"`` — N OS processes (spawn context: JAX is not
   fork-safe), each constructing its own engine + registry handle from a
   picklable spec and following the cross-process ``SERVING`` pointer.
+  CPU only: a parent on an accelerator holds its devices, so it refuses.
   A ``registry.promote`` by *any* process therefore hot-swaps every
   fleet member: each child polls the pointer file every engine tick.
 
@@ -162,6 +163,15 @@ class ServingFleet:
         if backend not in ("process", "inproc"):
             raise ValueError(f"backend must be 'process' or 'inproc', "
                              f"got {backend!r}")
+        if backend == "process":
+            import jax
+            if jax.default_backend() != "cpu":
+                raise RuntimeError(
+                    f"ServingFleet(backend='process') spawns {size} "
+                    f"processes that each import JAX, but this process "
+                    f"already holds the {jax.default_backend()!r} "
+                    f"backend: its children would contend for the same "
+                    f"devices.  Use backend='inproc' here.")
         opts = options if options is not None else EngineOptions()
         if opts.registry is None:
             raise ValueError(

@@ -1,8 +1,7 @@
 """Property-based SlotArena / StackedSlotArenas invariants.
 
-Random admit / free / migrate / multi-token-write sequences (hypothesis
-when installed, the deterministic ``tests/_hypothesis_fallback`` shim
-otherwise) against a host-side model: slots are never aliased, the free
+Random admit / free / migrate / multi-token-write sequences (hypothesis)
+against a host-side model: slots are never aliased, the free
 list and the active flags stay consistent, ``cache_index`` (the
 per-slot ``positions`` vector the decode masks are built from) is never
 corrupted, and every active slot's cache rows hold exactly the bytes
@@ -15,10 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:      # optional dep: deterministic fallback shim
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.models import api
 from repro.serving import SlotArena

@@ -97,8 +97,9 @@ def test_kernel_matches_jnp_cache_branch(kv_quant, window):
             p, cfg.replace(attn_impl="full"), x, positions=step[:, None],
             window=window, cache=cache, cache_index=step)
         out_k, cache_k = apply_attention(
-            p, cfg.replace(attn_impl="pallas"), x, positions=step[:, None],
-            window=window, cache=cache, cache_index=step)
+            p, cfg.replace(attn_impl="pallas", pallas_interpret=True), x,
+            positions=step[:, None], window=window, cache=cache,
+            cache_index=step)
         np.testing.assert_allclose(np.asarray(out_j), np.asarray(out_k),
                                    atol=1e-5, rtol=1e-5)
         for a, bb in zip(jax.tree_util.tree_leaves(cache_j),

@@ -268,6 +268,15 @@ def test_fleet_requires_registry(tiny_cfg):
         ServingFleet(tiny_cfg, size=2, options=EngineOptions())
 
 
+def test_process_fleet_refuses_off_cpu(tiny_cfg, monkeypatch):
+    """A parent on an accelerator holds its devices: spawning members
+    that each import JAX would make them contend for the same chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="contend"):
+        ServingFleet(tiny_cfg, size=2, options=EngineOptions(),
+                     backend="process")
+
+
 def test_rendezvous_affinity_is_consistent(fleet_plane):
     """Scaling a path's replicas up appends the next-ranked member and
     scaling down drops the tail — existing assignments never move."""

@@ -20,8 +20,9 @@ def test_flash_attention(s, h, kh, d, dtype, causal, window):
     q = jax.random.normal(ks[0], (2, s, h, d)).astype(dtype)
     k = jax.random.normal(ks[1], (2, s, kh, d)).astype(dtype)
     v = jax.random.normal(ks[2], (2, s, kh, d)).astype(dtype)
-    out = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              block_q=64, block_k=64, interpret=True)
+    out = ops.flash_attention_trainable(q, k, v, causal=causal,
+                                        window=window, block_q=64,
+                                        block_k=64, interpret=True)
     expect = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -124,7 +125,20 @@ def test_pallas_attn_impl_in_model():
     params, _ = api.init_model(key, cfg)
     batch = {"tokens": jax.random.randint(key, (2, 100), 0, cfg.vocab_size)}
     l1, _ = api.forward_logits(params, cfg.replace(attn_impl="full"), batch)
-    l2, _ = api.forward_logits(params, cfg.replace(attn_impl="pallas"),
-                               batch)
+    l2, _ = api.forward_logits(
+        params, cfg.replace(attn_impl="pallas", pallas_interpret=True), batch)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
                                atol=2e-4, rtol=2e-3)
+
+
+def test_pallas_never_interprets_unasked():
+    """Off the TPU, a compiled kernel refuses instead of silently
+    running the interpreter: interpret mode is the caller's choice."""
+    from repro.configs import get_smoke_config
+    from repro.models import api
+    cfg = get_smoke_config("dipaco-150m").replace(attn_impl="pallas")
+    key = jax.random.PRNGKey(0)
+    params, _ = api.init_model(key, cfg)
+    batch = {"tokens": jax.random.randint(key, (2, 16), 0, cfg.vocab_size)}
+    with pytest.raises(ValueError, match="interpret"):
+        api.forward_logits(params, cfg, batch)

@@ -123,8 +123,86 @@ def test_serve_rejects_unknown_engine(monkeypatch, capsys):
 def test_serve_oneshot_end_to_end(monkeypatch, capsys):
     from repro.launch import serve
     monkeypatch.setattr(sys, "argv", [
-        "serve", "--paths", "2", "--requests", "2", "--prompt-len", "8",
-        "--max-new", "2"])
+        "serve", "--smoke", "--paths", "2", "--requests", "2",
+        "--prompt-len", "8", "--max-new", "2"])
     serve.main()
     out = capsys.readouterr().out
     assert "tok/s" in out and "request->path" in out
+
+
+# ---------------------------------------------------------------------
+# entry points: no fallback that hides the device, and the compile cache
+# ---------------------------------------------------------------------
+
+def test_launchers_build_the_full_config_unless_smoke():
+    """On the CPU backend too: the preset is never chosen from the
+    backend, and interpret mode only comes with ``--smoke``."""
+    from repro.configs import get_config, get_smoke_config
+    from repro.launch import serve, train
+    full = serve.build_config(serve.build_parser().parse_args([]))
+    want = get_config("dipaco-150m")
+    assert (full.num_layers, full.d_model, full.vocab_size, full.dtype) == (
+        want.num_layers, want.d_model, want.vocab_size, want.dtype)
+    assert not full.pallas_interpret
+    smoke = serve.build_config(serve.build_parser().parse_args(["--smoke"]))
+    assert smoke.d_model == get_smoke_config("dipaco-150m").d_model
+    assert smoke.pallas_interpret
+    assert not train.build_parser().parse_args([]).smoke
+
+
+def test_compile_cache_defers_to_the_environment(monkeypatch, tmp_path):
+    import jax
+    from repro.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.compile_cache import enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        want = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            os.pardir, ".jax_cache")
+        assert path == os.path.realpath(want)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert enable_compile_cache() == path      # fixed, not per call
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
+
+
+ROOT = os.path.join(os.path.dirname(__file__), os.pardir)
+
+
+def test_benchmark_run_exits_nonzero_when_a_suite_raises(monkeypatch,
+                                                         capsys):
+    monkeypatch.syspath_prepend(ROOT)
+    from benchmarks import obs_overhead, run
+
+    def boom(quick=True):
+        raise RuntimeError("suite blew up")
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", os.devnull)
+    monkeypatch.setattr(obs_overhead, "run", boom)
+    monkeypatch.setattr(sys, "argv", ["run", "--only", "obs"])
+    with pytest.raises(SystemExit) as exc:
+        run.main()
+    assert exc.value.code == 1
+    assert "suite(s) raised: obs" in capsys.readouterr().err
+
+
+def test_mesh_lane_refuses_an_accelerator_parent(monkeypatch):
+    """The lane times forced host devices in a child; a parent holding
+    an accelerator would record CPU rows as mesh rows."""
+    import jax
+    monkeypatch.syspath_prepend(ROOT)
+    from benchmarks import outer_exec_scaling
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="forced CPU devices"):
+        outer_exec_scaling._mesh_lane_rows(quick=True)

@@ -5,10 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # optional dep: deterministic fallback examples
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.diloco import mix_deltas, outer_step
 from repro.core.partition import (make_partition, mixing_matrices,

@@ -116,7 +116,8 @@ _ENGINE_MATRIX = [
 
 def _serve_matrix_engine(cfg, two_paths, prompts, *, attn_impl, stacked,
                          bucketed, kv_quant, slots=2):
-    ecfg = cfg.replace(attn_impl=attn_impl, kv_quant=kv_quant)
+    ecfg = cfg.replace(attn_impl=attn_impl, kv_quant=kv_quant,
+                       pallas_interpret=True)
     eng = ContinuousBatchingEngine(ecfg, two_paths, options=EngineOptions(
         cache_len=48, slots_per_path=slots, stacked=stacked,
         bucketed_prefill=bucketed))

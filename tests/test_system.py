@@ -103,6 +103,26 @@ def test_flat_moe_config_is_fully_independent(tiny_cfg):
     np.testing.assert_allclose(mix_s, np.eye(4))
 
 
+def test_bf16_workers_train_f32_masters(tiny_cfg, setup):
+    """A bf16 model's workers train f32 master weights: one phase at the
+    default peak lr (4e-4) moves every global leaf, the norm scales at
+    1.0 included, where a bf16 copy rounds away any update below 2^-9."""
+    _, docs, doms, *_ = setup
+    cfg = tiny_cfg.replace(dtype="bfloat16")
+    ds = shard_documents(docs[:64], doms[:64] % 4, 4)
+    tr = DiPaCoTrainer(cfg, DiPaCoConfig(levels=(2, 2), inner_steps=2), ds,
+                       key=jax.random.PRNGKey(0), batch_size=2, warmup=1)
+    assert {x.dtype for x in jax.tree_util.tree_leaves(tr.worker_params)} \
+        == {jnp.dtype(jnp.float32)}
+    before = [np.asarray(x) for x in jax.tree_util.tree_leaves(
+        tr.global_params)]
+    tr.run_phase()
+    after = jax.tree_util.tree_leaves(tr.global_params)
+    still = [i for i, (a, b) in enumerate(zip(before, after))
+             if np.array_equal(a, np.asarray(b))]
+    assert still == [], still
+
+
 def test_serving_engine_generates(tiny_cfg, setup):
     corpus, docs, doms, val, _, base = setup
     from repro.serving import EngineOptions, PathServingEngine
@@ -149,3 +169,53 @@ print(json.dumps({"ok": True, "n_coll": stats["total_count"],
     assert out.returncode == 0, out.stderr[-2000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["ok"]
+
+
+def test_dense_train_step_lowers_on_explicit_mesh():
+    """The path model's tensor-parallel train step lowers under explicit
+    mesh axes (``jax.make_mesh``'s default): the vocab-sharded embedding
+    gather, the head- and MLP-sharded contractions and the loss's target
+    pick over sharded logits each get an unambiguous output layout."""
+    code = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import jax, jax.numpy as jnp
+from jax.sharding import AxisType, NamedSharding, PartitionSpec
+from repro.configs import get_smoke_config
+from repro.launch.sharding import shardings_for_tree
+from repro.launch.steps import (adamw_state_shapes, make_inner_train_step,
+                                worker_param_shapes)
+
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Explicit,) * 2)
+cfg = get_smoke_config("dipaco-150m")
+shapes, axes = worker_param_shapes(cfg, 4)
+sh = shardings_for_tree(shapes, axes, mesh, prepend=("worker",))
+wp = jax.tree_util.tree_map(
+    lambda s, d: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=d),
+    shapes, sh)
+opt = adamw_state_shapes(shapes)
+opt = {"m": jax.tree_util.tree_map(
+           lambda s, d: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=d),
+           opt["m"], sh),
+       "v": jax.tree_util.tree_map(
+           lambda s, d: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=d),
+           opt["v"], sh),
+       "count": jax.ShapeDtypeStruct((4,), jnp.int32, sharding=NamedSharding(
+           mesh, PartitionSpec("data")))}
+emb = sh["embed"]["embedding"].spec
+assert "model" in tuple(emb), emb          # the vocab axis is sharded
+batch = {"tokens": jax.ShapeDtypeStruct((4, 2, 64), jnp.int32,
+                                        sharding=NamedSharding(
+                                            mesh, PartitionSpec("data")))}
+lr = jax.ShapeDtypeStruct((), jnp.float32,
+                          sharding=NamedSharding(mesh, PartitionSpec()))
+jax.jit(make_inner_train_step(cfg)).lower(wp, opt, batch, lr).compile()
+print("ok")
+"""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=600, env=env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "ok"
