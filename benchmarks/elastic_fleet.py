@@ -53,7 +53,6 @@ def _service(s, ds, root, dcfg, **over):
 
 
 def _stable_row(s, ds, dcfg, phases, tel):
-    tel.instant("bench.section", section="fleet_stable")
     with tempfile.TemporaryDirectory() as root:
         with _service(s, ds, root, dcfg, telemetry=tel) as svc:
             svc.run(1, tau=2)              # warm the jit out of the timing
@@ -68,7 +67,6 @@ def _stable_row(s, ds, dcfg, phases, tel):
 
 
 def _loss30_row(s, ds, dcfg, phases, stable_loss, tel):
-    tel.instant("bench.section", section="fleet_loss30_recovered")
     with tempfile.TemporaryDirectory() as root:
         with _service(s, ds, root, dcfg, telemetry=tel) as svc:
             svc.run(1, tau=2)
@@ -102,7 +100,6 @@ def _loss30_row(s, ds, dcfg, phases, stable_loss, tel):
 
 
 def _flapping_row(s, ds, dcfg, phases, stable_loss, tel):
-    tel.instant("bench.section", section="fleet_flapping_faulty")
     noisy = dataclasses.replace(
         dcfg, transport_retries=12,
         transport_faults={"seed": 5, "drop": 0.15, "dup": 0.1,

@@ -169,7 +169,6 @@ def _streaming_rows(s, quick: bool):
     tel = common.make_telemetry("outer_exec")
     for name, over in variants.items():
         dcfg = DiPaCoConfig(levels=(2, 2), inner_steps=2, **over)
-        tel.instant("bench.section", section=f"outer_sync_{name}")
         with tempfile.TemporaryDirectory() as root:
             svc = TrainingService(
                 cfg, dcfg, ds, key=key, ckpt_root=root,

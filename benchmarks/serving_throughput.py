@@ -33,7 +33,7 @@ from repro.serving import (ContinuousBatchingEngine, EngineOptions,
                            PathServingEngine, Request, ServingFleet,
                            poisson_trace, prefix_hash_router)
 
-from .common import make_telemetry, record_bench
+from .common import record_bench
 
 
 def _percentiles(lat):
@@ -301,10 +301,9 @@ def run_fleet(quick: bool = True):
         span_1 = min(span_1s)
 
         from repro.serving import ServingFleet
-        tel = make_telemetry("fleet_serve")
         with ServingFleet(cfg, size=size, options=opts,
                           backend="process", seed=0,
-                          warmup=True, telemetry=tel) as fleet:
+                          warmup=True) as fleet:
             span_fs = []
             for _ in range(2):
                 t0 = time.perf_counter()
@@ -322,7 +321,6 @@ def run_fleet(quick: bool = True):
             swap_s = time.perf_counter() - t_swap
             routed = fleet.stats["routed"]
             rebalances = fleet.stats["rebalances"]
-        tel.close()
 
     if len(fins_f) != n or len(fins_1) != n:
         raise RuntimeError(f"fleet returned {len(fins_f)}/{n}, "
@@ -368,7 +366,7 @@ def run_fleet(quick: bool = True):
             "ttft_p50_s": _percentiles(tt)[0],
             "ttft_p95_s": _percentiles(tt)[1],
             "n": len(fl)})
-    record_bench("serving_fleet", rows, trace=tel.path)
+    record_bench("serving_fleet", rows)
     return rows
 
 

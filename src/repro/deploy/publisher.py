@@ -192,12 +192,8 @@ class Publisher:
                 if m is None:
                     return out
                 try:
-                    with self.tel.span("deploy.cycle",
-                                       version=m.version) as sp:
+                    with self.tel.span("deploy.cycle"):
                         out = self._cycle_body(out, m)
-                        sp.set(promoted=out["promoted"],
-                               rejected=out["rejected"],
-                               rolled_back=out["rolled_back"])
                     return out
                 except BaseException:
                     # crashed mid-cycle (gate error, promote died
@@ -222,30 +218,24 @@ class Publisher:
         if prev is not None and prev == m.version:
             return out
         if self.gate is not None and prev is not None:
-            with self.tel.span("deploy.canary", version=m.version,
-                               stage="canary") as sp:
+            with self.tel.span("deploy.canary"):
                 report = self.gate.evaluate(
                     self.registry.materialize(m.version),
                     self.registry.serving_paths())
-                sp.set(passed=bool(report.passed))
             out["report"] = report
             if not report.passed:
                 self._quarantine(m.signature)
                 self.rejected += 1
                 out["rejected"] = m.version
-                self.tel.instant("deploy.reject", version=m.version)
                 return out
         self.registry.promote(m.version)
         self.published += 1
         out["promoted"] = m.version
-        self.tel.instant("deploy.promote", version=m.version)
         if self.bake_gate is not None and prev is not None:
-            with self.tel.span("deploy.canary", version=m.version,
-                               stage="bake") as sp:
+            with self.tel.span("deploy.canary"):
                 bake = self.bake_gate.evaluate(
                     self.registry.serving_paths(),
                     self.registry.materialize(prev))
-                sp.set(passed=bool(bake.passed))
             out["report"] = bake
             if not bake.passed and self.auto_rollback:
                 self._quarantine(m.signature)
@@ -253,7 +243,6 @@ class Publisher:
                 self.rollbacks += 1
                 out["rolled_back"] = m.version
                 out["promoted"] = None
-                self.tel.instant("deploy.rollback", version=m.version)
         return out
 
     # -- background mode -----------------------------------------------

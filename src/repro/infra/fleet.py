@@ -154,10 +154,7 @@ class FleetController:
                    "epoch": int(self.epoch), **extra})
         # getattr: unit tests drive the controller with minimal
         # service fakes that predate the telemetry handle
-        getattr(svc, "tel", NULL).instant(
-            "fleet.epoch", epoch=int(self.epoch), action=action,
-            shards=[int(s) for s in shards],
-            members=[int(s) for s in members])
+        getattr(svc, "tel", NULL).instant("fleet.epoch")
         svc.execs.resize_membership(members)
 
     def restore_row(self, row) -> None:
@@ -240,9 +237,7 @@ class ChaosController:
             raise ValueError(f"unknown chaos action {act!r}")
         self.fired.append({"action": act, "applied": got,
                            "phase_clock": dict(svc.clock)})
-        getattr(svc, "tel", NULL).instant(
-            "fleet.chaos", action=act,
-            applied=got if isinstance(got, (int, list)) else list(got))
+        getattr(svc, "tel", NULL).instant("fleet.chaos")
 
     def _arm_mid(self, ev: dict, phase: int) -> None:
         """Fire ``ev`` right after the first train-row commit of

@@ -335,7 +335,7 @@ class TrainingService:
         # the vectorized trainer's schedule, recomputable after any
         # preemption
         t_start = time.perf_counter()
-        with self.tel.span("train.phase", shard=shard, phase=t) as sp:
+        with self.tel.span("train.phase", shard=shard, phase=t):
             batches = jnp.asarray(phase_batches(
                 self.loaders[shard].tokens, self.loaders[shard].batch_size,
                 tau, shard, t))
@@ -348,7 +348,6 @@ class TrainingService:
                 lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
                 params0, params)
             loss = float(np.asarray(losses).mean())
-            sp.set(loss=loss)
             prof = self.profiles.get(shard)
             if prof is not None and prof.compute < 1.0:
                 # heterogeneous compute: a slow machine's phase takes

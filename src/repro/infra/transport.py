@@ -123,7 +123,7 @@ class MeshTransport:
         return self.devices[shard % len(self.devices)]
 
     def ship(self, shard: int, wire, payload, *, phase=None):
-        with self.tel.span("transport.ship", shard=shard, phase=phase):
+        with self.tel.span("transport.ship"):
             return self._ship(shard, wire, payload, phase=phase)
 
     def _ship(self, shard: int, wire, payload, *, phase=None):
@@ -350,7 +350,6 @@ class RetryingTransport:
         with self._lock:
             self._stats["retries"] += 1
         b = self.policy.backoff(attempt)
-        self.tel.instant("transport.retry", shard=shard, phase=phase,
-                         attempt=attempt, reason=reason, backoff_s=b)
+        self.tel.instant("transport.retry", shard=shard)
         if b:
             self._sleep(b)

@@ -79,11 +79,8 @@ class WorkerPool:
                 if self.rng.random() < p:
                     with self._lock:
                         self.preemptions += 1
-                    self.tel.instant("pool.preempt", worker=wid,
-                                     pool=self.name)
                     raise Preempted(f"worker {wid} preempted")
-                with self.tel.span("pool.task", worker=wid,
-                                   kind=task.kind):
+                with self.tel.span("pool.task"):
                     result = self.handler(task)
                 self.queue.complete(task.task_id, result)
                 with self._lock:
@@ -184,9 +181,6 @@ class Monitor:
             # so a concurrent resize can't be double-counted
             n = self.pool._reconcile()
             self.restarts += n
-            if n:
-                self.pool.tel.instant("pool.restart", n=n,
-                                      pool=self.pool.name)
 
     def start(self):
         self._thread.start()

@@ -6,45 +6,70 @@ pool, deploy publisher, and serving engine all accept
 ``telemetry=None`` and pay nothing when it is absent (``NULL`` is a
 shared no-op whose ``span`` returns a singleton context manager).
 
-Enabled, it provides:
+A span goes to two sinks:
 
-- ``span(name, **args)`` / ``instant(name, **args)`` — structured
-  spans and events into a crash-safe JSONL trace (``trace.py``),
-- a typed :class:`~repro.obs.metrics.MetricRegistry` (``.metrics``)
-  with lock-free hot-path recording,
-- ``sample_metrics()`` — snapshot the registry into the trace as a
-  counter record,
-- exporters: Chrome/Perfetto ``trace_event`` JSON (``perfetto.py``)
-  and a summary CLI (``python -m repro.obs``).
+- the ``jax.profiler`` capture, when one is running: ``span(name,
+  **args)`` enters a ``jax.profiler.TraceAnnotation`` with the args as
+  its metadata, on the device trace's clock, whether the handle is
+  ``NULL`` or live.  A span whose args hold ``step_num`` is a step
+  annotation (``StepTraceAnnotation``).  jax is imported on the first
+  span, so ``python -m repro.obs`` needs no accelerator.
+- the crash-safe JSONL trace (``trace.py``), when ``Telemetry`` was
+  given a path.
 
-Span/event name vocabulary (``plane.component``):
+``complete_span`` (a span whose start was captured earlier, such as
+``serve.swap``) and ``instant`` go to the JSONL trace only.  Enabled,
+the handle also provides a typed
+:class:`~repro.obs.metrics.MetricRegistry` (``.metrics``) with
+lock-free hot-path recording, ``sample_metrics()`` (a snapshot of the
+registry into the trace as a counter record), and exporters:
+Chrome/Perfetto ``trace_event`` JSON (``perfetto.py``) and a summary
+CLI (``python -m repro.obs``).
 
-======================  ============================================
-``train.phase``         one shard×phase inner-loop execution
-``train.fragment_send`` one fragment slot shipped on the wire
-``train.run``           one ``TrainingService.run`` window
-``transport.ship``      mesh transport device round-trip
-``transport.retry``     instant: a send attempt failed and backed off
-``fleet.epoch``         instant: membership epoch commit
-``fleet.chaos``         instant: chaos controller action
-``pool.task``           worker-pool task execution
-``pool.preempt``        instant: simulated worker preemption
-``pool.restart``        instant: monitor restarted dead workers
-``deploy.cycle``        one publisher publish cycle
-``deploy.canary``       canary gate evaluation
-``deploy.promote`` / ``deploy.reject`` / ``deploy.rollback``  instants
-``serve.tick``          one continuous-batching engine step
-``serve.swap``          engine hot-swap window (drain start→install)
-``serve.admit``         instant: request admitted to a slot
-``serve.preempt``       instant: high-priority admit evicted a
-                        preemptible slot (evictee re-queues)
-``serve.route``         instant: fleet front door dispatched a request
-``serve.rebalance``     instant: fleet recomputed per-path replicas
-======================  ============================================
+Span/event name vocabulary (``plane.component``).  A name, or an
+argument, is here because something reads it: ``python -m repro.obs
+summary`` (``summary.py``), the README's Observability section, or a
+benchmark metric (``bench/metrics/``).
+
+========================  =============================================
+``train.phase``           one shard×phase inner-loop execution
+                          (``shard``, ``phase``: summary)
+``train.fragment_send``   one fragment slot shipped on the wire
+                          (``shard``, ``phase``: summary)
+``transport.ship``        mesh transport device round-trip
+``transport.retry``       instant: a send attempt failed and backed off
+                          (``shard``: summary)
+``fleet.epoch``           instant: membership epoch commit
+``fleet.chaos``           instant: chaos controller action
+``pool.task``             worker-pool task execution
+``deploy.cycle``          one publisher publish cycle
+``deploy.canary``         canary gate evaluation
+``serve.swap``            engine hot-swap window (drain start→install)
+``serve.tick``            one continuous-batching engine step
+                          (``step_num``: the tick)
+``serve.schedule``        swap poll, routing, preemption and the
+                          admissions decision
+``serve.admit``           one island's admissions (``path``,
+                          ``requests``)
+``serve.prefill``         one prefill dispatch (``rows``,
+                          ``padded_rows``, ``tokens``: real prompt
+                          tokens, ``padded_tokens``: the tokens the
+                          program computes, ``bucket``)
+``serve.fetch``           a device-to-host copy of logits (``bytes``)
+``serve.decode``          the tick's decode dispatches (``rows``,
+                          ``slots``, ``dense``)
+``serve.emit``            greedy tokens, retirement, migration
+                          (``rows``, ``finished``)
+========================  =============================================
+
+The ``serve.*`` spans inside ``serve.tick`` are read from a profiler
+capture by the benchmark's ``idle_in_*_ms``,
+``logits_to_host_mb_per_tick`` and ``prefill_useful_share`` metrics.
 """
 
 from __future__ import annotations
 
+import functools
 import time
 
 from .metrics import Counter, Gauge, Histogram, MetricRegistry
@@ -83,6 +108,54 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+@functools.cache
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation`` with the span's ``set``."""
+    from jax.profiler import TraceAnnotation
+
+    class Annotation(TraceAnnotation):
+        __slots__ = ()
+
+        def set(self, **kv):
+            self.set_metadata(**kv)
+
+    return Annotation
+
+
+def _annotation(name, args):
+    """The span's profiler sink: an annotation while a capture runs,
+    else ``None`` (nothing is allocated)."""
+    cls = _annotation_type()
+    if not cls.is_enabled():
+        return None
+    if "step_num" in args:
+        return cls(name, _r=1, **args)    # what StepTraceAnnotation passes
+    return cls(name, **args)
+
+
+class _PairedSpan:
+    """One span in both sinks: the JSONL trace and the profiler."""
+
+    __slots__ = ("_jsonl", "_profiler")
+
+    def __init__(self, jsonl, profiler):
+        self._jsonl, self._profiler = jsonl, profiler
+
+    def __enter__(self):
+        self._profiler.__enter__()
+        self._jsonl.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._jsonl.__exit__(*exc)
+        self._profiler.__exit__(*exc)
+        return False
+
+    def set(self, **kv):
+        self._jsonl.set(**kv)
+        self._profiler.set(**kv)
+
+
 class NullTelemetry:
     """Disabled telemetry: every call is a no-op.
 
@@ -99,7 +172,8 @@ class NullTelemetry:
     trace = None
 
     def span(self, name, **args):
-        return _NULL_SPAN
+        ann = _annotation(name, args)
+        return _NULL_SPAN if ann is None else ann
 
     def complete_span(self, name, t0_ns, **args):
         pass
@@ -144,9 +218,11 @@ class Telemetry:
         return self.trace.epoch if self.trace is not None else 0
 
     def span(self, name, **args):
+        ann = _annotation(name, args)
         if self.trace is None:
-            return _NULL_SPAN
-        return self.trace.span(name, **args)
+            return _NULL_SPAN if ann is None else ann
+        jsonl = self.trace.span(name, **args)
+        return jsonl if ann is None else _PairedSpan(jsonl, ann)
 
     def complete_span(self, name, t0_ns, **args):
         """Record a span whose start was captured earlier (e.g. an

@@ -17,8 +17,8 @@ Design contract (PR-8 lock discipline):
   own outer lock, which makes the numbers exact.
 
 Naming convention (documented in README "Observability"):
-``plane.component.metric`` — e.g. ``train.comm.send_bytes``,
-``serve.engine.ticks``, ``deploy.canary.verdicts``.
+``plane.component.metric`` — ``train.comm.send_bytes`` and
+``train.phase.wall_s``.
 """
 
 from __future__ import annotations

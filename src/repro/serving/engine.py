@@ -286,8 +286,7 @@ class PathServingEngine(_EngineBase):
             return False
         t0 = time.monotonic_ns()
         self._version, self.paths = self.registry.serving()
-        self.tel.complete_span("serve.swap", t0, policy="drain",
-                               version=self._version)
+        self.tel.complete_span("serve.swap", t0)
         return True
 
     def device_state(self):
@@ -548,8 +547,7 @@ class ContinuousBatchingEngine(_EngineBase):
             t0 = time.monotonic_ns()
             self._install(version, paths)
             self._reprefill_inflight()
-            self.tel.complete_span("serve.swap", t0, policy="live",
-                                   version=version, tick=self.ticks)
+            self.tel.complete_span("serve.swap", t0)
             return False
         if self.in_flight:
             # drain: in-flight requests finish on their admitted
@@ -560,8 +558,7 @@ class ContinuousBatchingEngine(_EngineBase):
         t0 = self._swap_wait_ns or time.monotonic_ns()
         self._swap_wait_ns = None
         self._install(version, paths)
-        self.tel.complete_span("serve.swap", t0, policy="drain",
-                               version=version, tick=self.ticks)
+        self.tel.complete_span("serve.swap", t0)
         return False
 
     def _prefill_running(self, path: int, tokens):
@@ -569,18 +566,25 @@ class ContinuousBatchingEngine(_EngineBase):
         (the §2.4.3 migration primitive shared by re-route moves and
         live hot-swaps): returns (next-token logits row, cache)."""
         n = len(tokens)
-        if self.bucketed:
-            length = self._bucket(n)
-            tok = np.zeros((1, length), np.int32)
-            tok[0, :n] = tokens
-            logits, cache = self._prefill_bucketed(
-                self.paths[path], jnp.asarray(tok),
-                jnp.asarray([n - 1], np.int32))
-        else:
-            logits, cache = self._prefill(
-                self.paths[path],
-                jnp.asarray(np.asarray(tokens, np.int32)[None]))
-        return np.asarray(logits)[0], cache
+        length = self._bucket(n) if self.bucketed else n
+        with self.tel.span("serve.prefill", rows=1, padded_rows=1, tokens=n,
+                           padded_tokens=length, bucket=length):
+            if self.bucketed:
+                tok = np.zeros((1, length), np.int32)
+                tok[0, :n] = tokens
+                logits, cache = self._prefill_bucketed(
+                    self.paths[path], jnp.asarray(tok),
+                    jnp.asarray([n - 1], np.int32))
+            else:
+                logits, cache = self._prefill(
+                    self.paths[path],
+                    jnp.asarray(np.asarray(tokens, np.int32)[None]))
+            return self._fetch(logits)[0], cache
+
+    def _fetch(self, logits) -> np.ndarray:
+        """Copy logits to the host: where a tick waits for the device."""
+        with self.tel.span("serve.fetch", bytes=logits.nbytes):
+            return np.asarray(logits)
 
     def _reprefill_inflight(self) -> None:
         """Live-swap migration: rebuild every in-flight request's cache
@@ -693,24 +697,30 @@ class ContinuousBatchingEngine(_EngineBase):
     def step(self, now: float = 0.0) -> List[FinishedRequest]:
         """Advance the engine one tick; returns requests finished now."""
         self.ticks += 1
-        with self.tel.span("serve.tick", tick=self.ticks) as sp:
-            draining = self._poll_swap()
-            self.scheduler.route_arrivals(self._route_prompt)
-            if not draining:
-                if self.preemption:
-                    self._preempt_tick()
-                admissions = self.scheduler.admissions(
-                    {p: a.num_free for p, a in enumerate(self.arenas)})
-                for p, reqs in admissions.items():
+        with self.tel.span("serve.tick", step_num=self.ticks):
+            admissions = {}
+            with self.tel.span("serve.schedule"):
+                draining = self._poll_swap()
+                self.scheduler.route_arrivals(self._route_prompt)
+                if not draining:
+                    if self.preemption:
+                        self._preempt_tick()
+                    admissions = self.scheduler.admissions(
+                        {p: a.num_free for p, a in enumerate(self.arenas)})
+                elif self.scheduler.pending:
+                    # the drain pause is backpressure too: requests are
+                    # waiting on the swap, not on slots — count every
+                    # queued request starved by the stall
+                    self.scheduler.drain_backpressure()
+            for p, reqs in admissions.items():
+                with self.tel.span("serve.admit", path=p,
+                                   requests=len(reqs)):
                     self._admit(p, reqs, now)
-            elif self.scheduler.pending:
-                # the drain pause is backpressure too: requests are
-                # waiting on the swap, not on slots — count every
-                # queued request starved by the stall
-                self.scheduler.drain_backpressure()
             self._decode_tick()
-            fins = self._emit_tick(now)
-            sp.set(in_flight=len(self.in_flight), finished=len(fins))
+            with self.tel.span("serve.emit",
+                               rows=len(self.in_flight)) as sp:
+                fins = self._emit_tick(now)
+                sp.set(finished=len(fins))
         return fins
 
     def _preempt_tick(self) -> None:
@@ -741,8 +751,6 @@ class ContinuousBatchingEngine(_EngineBase):
                 self._preempted[st.req.rid] = st
                 self.scheduler.requeue(st.req, p)
                 self.scheduler.stats.preemptions += 1
-                self.tel.instant("serve.preempt", path=p, rid=st.req.rid,
-                                 emitted=st.emitted)
 
     def _prefix_admit(self, path: int, r: Request, arena,
                       now: float) -> bool:
@@ -766,13 +774,15 @@ class ContinuousBatchingEngine(_EngineBase):
             # copy the stored row: the replay loop donates its cache
             # argument, which must not consume the cached entry
             row = jax.tree_util.tree_map(jnp.array, row)
-            lg = None
-            for t in range(n, s0):
-                lg, row = self._extend(
-                    self.paths[path],
-                    jnp.asarray([[r.prompt[t]]], jnp.int32),
-                    row, jnp.int32(t))
-            logits = np.asarray(lg)[0]
+            with self.tel.span("serve.prefill", rows=1, padded_rows=1,
+                               tokens=s0 - n, padded_tokens=s0 - n,
+                               bucket=s0 - n):
+                for t in range(n, s0):
+                    lg, row = self._extend(
+                        self.paths[path],
+                        jnp.asarray([[r.prompt[t]]], jnp.int32),
+                        row, jnp.int32(t))
+                logits = self._fetch(lg)[0]
             self.prefix_cache.put(path, self._version, r.prompt, row,
                                   logits)
         slot = arena.alloc()
@@ -800,7 +810,6 @@ class ContinuousBatchingEngine(_EngineBase):
         Fallback: batch-1 exact-length prefill per request (compile
         cache bounded by distinct prompt lengths).
         """
-        self.tel.instant("serve.admit", path=path, n=len(reqs))
         arena = self.arenas[path]
         fresh: List[Request] = []
         for r in reqs:
@@ -825,19 +834,22 @@ class ContinuousBatchingEngine(_EngineBase):
         if not self.bucketed:
             for r in reqs:
                 s0 = len(r.prompt)
-                logits, cache = self._prefill(self.paths[path],
-                                              jnp.asarray(r.prompt[None]))
-                slot = arena.alloc()
-                arena.write_slots(cache, [slot], [s0])
+                with self.tel.span("serve.prefill", rows=1, padded_rows=1,
+                                   tokens=s0, padded_tokens=s0, bucket=s0):
+                    logits, cache = self._prefill(
+                        self.paths[path], jnp.asarray(r.prompt[None]))
+                    slot = arena.alloc()
+                    arena.write_slots(cache, [slot], [s0])
+                    logits = self._fetch(logits)[0]
                 self.in_flight[r.rid] = RequestState(
                     req=r, path=path, slot=slot,
                     tokens=list(map(int, r.prompt)),
-                    next_logits=np.asarray(logits)[0],
+                    next_logits=logits,
                     prefilled_this_tick=True, admitted_at=now,
                     version=self._version)
                 if self.prefix_cache is not None:
                     self.prefix_cache.put(path, self._version, r.prompt,
-                                          cache, np.asarray(logits)[0])
+                                          cache, logits)
             return
         groups: Dict[int, List[Request]] = {}
         for r in reqs:
@@ -849,12 +861,16 @@ class ContinuousBatchingEngine(_EngineBase):
             for i, r in enumerate(group):
                 tok[i, :len(r.prompt)] = r.prompt
                 last[i] = len(r.prompt) - 1
-            logits, cache = self._prefill_bucketed(
-                self.paths[path], jnp.asarray(tok), jnp.asarray(last))
-            slots = [arena.alloc() for _ in group]
-            arena.write_slots(cache, slots,
-                              [len(r.prompt) for r in group])
-            logits = np.asarray(logits)
+            with self.tel.span("serve.prefill", rows=len(group),
+                               padded_rows=rows,
+                               tokens=sum(len(r.prompt) for r in group),
+                               padded_tokens=rows * length, bucket=length):
+                logits, cache = self._prefill_bucketed(
+                    self.paths[path], jnp.asarray(tok), jnp.asarray(last))
+                slots = [arena.alloc() for _ in group]
+                arena.write_slots(cache, slots,
+                                  [len(r.prompt) for r in group])
+                logits = self._fetch(logits)
             for i, r in enumerate(group):
                 self.in_flight[r.rid] = RequestState(
                     req=r, path=path, slot=slots[i],
@@ -877,46 +893,53 @@ class ContinuousBatchingEngine(_EngineBase):
         once per tick, not once per island.  Fallback: one masked
         full-arena decode step per island with work.
         """
-        if self.stacked:
-            self._decode_tick_stacked()
+        rows = [st for st in self.in_flight.values()
+                if not st.prefilled_this_tick]
+        if not rows:
             return
-        for p, arena in enumerate(self.arenas):
-            rows = [st for st in self.in_flight.values()
-                    if st.path == p and not st.prefilled_this_tick]
-            if not rows:
-                continue
+        active = sorted({st.path for st in rows})
+        # the stacked tick decodes every island when half have work
+        dense = self.stacked and 2 * len(active) >= len(self.arenas)
+        islands = len(self.arenas) if dense else len(active)
+        with self.tel.span("serve.decode", rows=len(rows),
+                           slots=islands * self.arenas[0].num_slots,
+                           dense=dense):
+            if self.stacked:
+                self._decode_tick_stacked(rows, active, dense)
+            else:
+                self._decode_tick_islands(rows, active)
+
+    def _decode_tick_islands(self, rows, active) -> None:
+        for p in active:
+            arena = self.arenas[p]
+            mine = [st for st in rows if st.path == p]
             tok = np.zeros((arena.num_slots, 1), np.int32)
             mask = np.zeros(arena.num_slots, bool)
-            for st in rows:
+            for st in mine:
                 arena.positions[st.slot] = len(st.tokens) - 1
                 tok[st.slot, 0] = st.tokens[-1]
                 mask[st.slot] = True
             logits, arena.cache = self._decode_masked(
                 self.paths[p], jnp.asarray(tok), arena.cache,
                 jnp.asarray(arena.decode_indices()), jnp.asarray(mask))
-            logits = np.asarray(logits)
-            for st in rows:
+            logits = self._fetch(logits)
+            for st in mine:
                 st.next_logits = logits[st.slot]
 
-    def _decode_tick_stacked(self) -> None:
+    def _decode_tick_stacked(self, rows, active, dense) -> None:
         sa = self._stacked_arenas
-        rows = [st for st in self.in_flight.values()
-                if not st.prefilled_this_tick]
-        if not rows:
-            return
         tok = np.zeros((sa.num_paths, sa.num_slots, 1), np.int32)
         mask = np.zeros((sa.num_paths, sa.num_slots), bool)
         for st in rows:
             sa.positions[st.path, st.slot] = len(st.tokens) - 1
             tok[st.path, st.slot, 0] = st.tokens[-1]
             mask[st.path, st.slot] = True
-        active = sorted({st.path for st in rows})
-        if 2 * len(active) >= sa.num_paths:
+        if dense:
             # dense tick: one vmapped dispatch advances every island
             logits, sa.cache = self._decode_stacked(
                 self._stacked_params, jnp.asarray(tok), sa.cache,
                 jnp.asarray(sa.positions), jnp.asarray(mask))
-            logits = np.asarray(logits)
+            logits = self._fetch(logits)
             for st in rows:
                 st.next_logits = logits[st.path, st.slot]
             return
@@ -928,7 +951,7 @@ class ContinuousBatchingEngine(_EngineBase):
                 self.paths[p], jnp.int32(p), jnp.asarray(tok[p]),
                 sa.cache, jnp.asarray(sa.positions[p]),
                 jnp.asarray(mask[p]))
-            out[p] = np.asarray(lg)
+            out[p] = self._fetch(lg)
         for st in rows:
             st.next_logits = out[st.path][st.slot]
 

@@ -48,8 +48,6 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from repro.obs import as_telemetry
-
 from .engine import ContinuousBatchingEngine, EngineOptions, \
     FinishedRequest
 from .scheduler import Request, prefix_hash_router
@@ -156,8 +154,7 @@ class ServingFleet:
     def __init__(self, cfg, *, size: int,
                  options: Optional[EngineOptions] = None,
                  backend: str = "process", seed: int = 0,
-                 warmup: bool = False, rebalance_every: int = 64,
-                 telemetry=None):
+                 warmup: bool = False, rebalance_every: int = 64):
         if size < 1:
             raise ValueError(f"fleet size must be >= 1, got {size}")
         if backend not in ("process", "inproc"):
@@ -182,8 +179,6 @@ class ServingFleet:
         self.backend = backend
         self.options = opts
         self.registry = opts.registry
-        self.tel = as_telemetry(telemetry if telemetry is not None
-                                else opts.telemetry)
         self.num_paths = self.registry.num_paths
         self.route_fn = (opts.route_fn if opts.route_fn is not None
                          else prefix_hash_router(self.num_paths))
@@ -264,8 +259,6 @@ class ServingFleet:
         self._outstanding_by_path[path] += 1
         self._rid_engine[req.rid] = (engine, path)
         self.stats["routed"] += 1
-        self.tel.instant("serve.route", rid=req.rid, path=path,
-                         engine=engine, replicas=len(cand))
         if self.backend == "inproc":
             self.engines[engine].submit(req)
         else:
@@ -286,9 +279,6 @@ class ServingFleet:
             self.replicas[p] = max(1, min(self.size, want))
             self._starved_since[p] = 0
         self.stats["rebalances"] += 1
-        self.tel.instant("serve.rebalance",
-                         hot=max(self.replicas.values()),
-                         paths=self.num_paths)
 
     # -- member feedback -----------------------------------------------
     def _merge_starved(self, wid: int, starved_by_path: dict) -> None:
@@ -450,7 +440,6 @@ class ServingFleet:
                 f.finished_at -= t0
                 if f.first_token_at:
                     f.first_token_at -= t0
-        self.tel.flush()
         return sorted(out, key=lambda f: f.rid)
 
     # -- lifecycle ------------------------------------------------------
