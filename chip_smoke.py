@@ -191,9 +191,10 @@ def decode_tick_seconds(engine, n: int = 10) -> float:
     import jax
     import jax.numpy as jnp
     sa = engine._stacked_arenas
-    tok = jnp.zeros((sa.num_paths, sa.num_slots, 1), jnp.int32)
-    mask = jnp.ones((sa.num_paths, sa.num_slots), bool)
-    pos = jnp.asarray(sa.positions)
+    rows = sa.num_paths * sa.num_slots
+    tok = jnp.zeros((rows, 1), jnp.int32)
+    mask = jnp.ones((rows,), bool)
+    pos = jnp.asarray(sa.positions.reshape(-1))
     times = []
     for _ in range(n):
         t0 = time.perf_counter()

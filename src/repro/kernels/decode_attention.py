@@ -14,6 +14,10 @@ dense ``(B, H, S, T)`` masked-einsum cache branch of
   slot is reconstructed inside the kernel, masking unwritten slots,
   causally-future slots and window-expired slots; fully-invalid key
   blocks skip their matmuls entirely (``pl.when``);
+* **in-place, layer-indexed read** — the cache is the decode step's
+  whole layer-stacked buffer ``(L, N, KH, D, T)``; the layer and a row
+  offset arrive by scalar prefetch and the block index map reads the
+  right layer's rows, so nothing is sliced or transposed for the kernel;
 * **fused int8 dequantization** — with a quantized cache
   (``cfg.kv_quant``) the int8 K/V blocks and their per-(token, head)
   scales are dequantized in VMEM right before the dot, so the quantized
@@ -58,7 +62,7 @@ def _block_has_valid(j, ci, *, T: int, block_k: int,
     return jnp.logical_or(n >= T, jnp.where(first >= 0, plain, wrapped))
 
 
-def _decode_kernel(ci_ref, q_ref, k_ref, v_ref, *rest,
+def _decode_kernel(ci_ref, _at_ref, q_ref, k_ref, v_ref, *rest,
                    quantized: bool, T: int, block_k: int, nk: int,
                    kh: int, g: int, d: int, window: Optional[int],
                    scale: float):
@@ -131,49 +135,58 @@ def _pick_block_k(T: int, block_k: int) -> int:
     return block_k if T % block_k == 0 else T
 
 
-def flash_decode(q, k_cache, v_cache, cache_index, *,
-                 window: Optional[int] = None, k_scale=None, v_scale=None,
-                 block_k: int = 128, interpret: bool = False):
-    """Single-token decode attention over a ring KV cache.
+def flash_decode(q, k_cache, v_cache, cache_index, layer=0, *,
+                 row_offset=0, window: Optional[int] = None, k_scale=None,
+                 v_scale=None, block_k: int = 128, interpret: bool = False):
+    """Single-token decode attention over one layer of a layer-stacked
+    ring KV cache, read in place.
 
     q: (B, H, D) — the current token's queries (RoPE already applied).
-    k_cache, v_cache: (B, T, KH, D) ring caches, f32/bf16 — or int8 with
-    ``k_scale``/``v_scale`` (B, T, KH) per-(token, head) scales.
+    k_cache, v_cache: (L, N, KH, D, T) ring caches of L layers and N
+    rows, tokens minor, f32/bf16 — or int8 with ``k_scale``/``v_scale``
+    (L, N, KH, T) per-(token, head) scales.
     cache_index: (B,) int32 — each row's decode position (the position
     the current token was just written at; masking admits ring entries
     with absolute position in ``[max(0, ci-window+1), ci]``).
+    layer, row_offset: int32 scalars, static or traced: query row ``b``
+    attends cache row ``row_offset + b`` of layer ``layer``.
 
-    The kernel reads the caches in place.  On a TPU a ``(B, T, KH, D)``
-    cache with D < 128 lies in memory as ``(B, KH, D, T)``, tokens along
-    lanes (its default layout), so the kernel takes that view, which XLA
-    makes a bitcast, and the scales as ``(B, KH, T)`` likewise.  Each
-    grid step's block is ``(KH, D, block_k)``: minor dimensions the
-    tiling rule accepts for D a multiple of 8, and one DMA for all heads.
+    Both scalars reach the kernel by scalar prefetch and pick each grid
+    step's block in the ``index_map``, ``(layer, row_offset + b, 0, 0,
+    j)``: the kernel DMAs its ``(KH, D, block_k)`` blocks straight out of
+    the whole stacked buffer, so a decode step that carries the cache
+    through its layer loop slices, transposes and copies nothing.  The
+    tokens-minor order is the one a TPU picks for a ``(..., D, T)``
+    buffer with D < 128; blocks have minor dimensions the tiling rule
+    accepts for D a multiple of 8, one DMA for all heads.
 
     Returns (B, H, D) in q's dtype.
     """
     b, h, d = q.shape
-    T, kh = k_cache.shape[1], k_cache.shape[2]
+    kh, T = k_cache.shape[2], k_cache.shape[4]
     assert h % kh == 0, (h, kh)
     quantized = k_scale is not None
     assert quantized == (v_scale is not None)
     bk = _pick_block_k(T, block_k)
     nk = T // bk
     ci = jnp.asarray(cache_index, jnp.int32).reshape(b)
+    at = jnp.stack([jnp.asarray(layer, jnp.int32),
+                    jnp.asarray(row_offset, jnp.int32)])
     kernel = functools.partial(
         _decode_kernel, quantized=quantized, T=T, block_k=bk, nk=nk,
         kh=kh, g=h // kh, d=d, window=window, scale=1.0 / math.sqrt(d))
-    q_spec = pl.BlockSpec((1, h, d), lambda bi, j, ci: (bi, 0, 0))
-    kv_spec = pl.BlockSpec((1, kh, d, bk), lambda bi, j, ci: (bi, 0, 0, j))
+    q_spec = pl.BlockSpec((1, h, d), lambda bi, j, ci, at: (bi, 0, 0))
+    kv_spec = pl.BlockSpec((pl.squeezed, 1, kh, d, bk),
+                           lambda bi, j, ci, at: (at[0], at[1] + bi, 0, 0, j))
     in_specs = [q_spec, kv_spec, kv_spec]
-    args = [q] + [jnp.transpose(c, (0, 2, 3, 1)) for c in (k_cache, v_cache)]
+    args = [q, k_cache, v_cache]
     if quantized:
-        sc_spec = pl.BlockSpec((1, kh, bk), lambda bi, j, ci: (bi, 0, j))
+        sc_spec = pl.BlockSpec((pl.squeezed, 1, kh, bk),
+                               lambda bi, j, ci, at: (at[0], at[1] + bi, 0, j))
         in_specs += [sc_spec, sc_spec]
-        args += [jnp.swapaxes(x.astype(jnp.float32), 1, 2)
-                 for x in (k_scale, v_scale)]
+        args += [x.astype(jnp.float32) for x in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, nk),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -189,4 +202,4 @@ def flash_decode(q, k_cache, v_cache, cache_index, *,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
         name="flash_decode",
-    )(ci, *args)
+    )(ci, at, *args)

@@ -21,13 +21,16 @@ from .ssd_scan import ssd_scan as _ssd
 
 @functools.partial(jax.jit, static_argnames=("window", "block_k",
                                              "interpret"))
-def decode_attention(q, k_cache, v_cache, cache_index, *, window=None,
-                     k_scale=None, v_scale=None, block_k=128,
-                     interpret=False):
-    """Flash-decode: single-token GQA attention over the ring KV cache
-    (split-K online softmax, in-kernel ring/window masking, fused int8
-    dequant).  q: (B, H, D); caches (B, T, KH, D); cache_index (B,)."""
-    return _flash_decode(q, k_cache, v_cache, cache_index, window=window,
+def decode_attention(q, k_cache, v_cache, cache_index, layer=0, *,
+                     row_offset=0, window=None, k_scale=None, v_scale=None,
+                     block_k=128, interpret=False):
+    """Flash-decode: single-token GQA attention over one layer of the
+    layer-stacked ring KV cache, read in place (split-K online softmax,
+    in-kernel ring/window masking, fused int8 dequant).  q: (B, H, D);
+    caches (L, N, KH, D, T); cache_index (B,); query row b reads cache
+    row ``row_offset + b`` of layer ``layer``."""
+    return _flash_decode(q, k_cache, v_cache, cache_index, layer,
+                         row_offset=row_offset, window=window,
                          k_scale=k_scale, v_scale=v_scale, block_k=block_k,
                          interpret=interpret)
 

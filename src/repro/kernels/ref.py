@@ -13,22 +13,28 @@ def flash_attention_ref(q, k, v, *, causal=True, window=None):
     return full_attention(q, k, v, causal=causal, window=window)
 
 
-def flash_decode_ref(q, k_cache, v_cache, cache_index, *, window=None,
-                     k_scale=None, v_scale=None):
+def flash_decode_ref(q, k_cache, v_cache, cache_index, layer=0, *,
+                     row_offset=0, window=None, k_scale=None, v_scale=None):
     """Dense oracle for kernels.decode_attention: single-token GQA over
-    a ring cache with per-row positions and optional int8 KV scales."""
+    one layer of a layer-stacked ring cache ``(L, N, KH, D, T)``, query
+    row b on cache row ``row_offset + b``, with per-row positions and
+    optional int8 KV scales ``(L, N, KH, T)``."""
     NEG_INF = -1e30
     b, h, d = q.shape
-    T, kh = k_cache.shape[1], k_cache.shape[2]
+    kh, T = k_cache.shape[2], k_cache.shape[4]
     g = h // kh
+
+    def rows(c):
+        return jax.lax.dynamic_slice_in_dim(c[layer], row_offset, b, 0)
+
     ci = jnp.asarray(cache_index, jnp.int32).reshape(b)
-    kf = k_cache.astype(jnp.float32)
-    vf = v_cache.astype(jnp.float32)
+    kf = rows(k_cache).astype(jnp.float32)               # (B, KH, D, T)
+    vf = rows(v_cache).astype(jnp.float32)
     if k_scale is not None:
-        kf = kf * k_scale.astype(jnp.float32)[..., None]
-        vf = vf * v_scale.astype(jnp.float32)[..., None]
+        kf = kf * rows(k_scale).astype(jnp.float32)[:, :, None, :]
+        vf = vf * rows(v_scale).astype(jnp.float32)[:, :, None, :]
     qg = q.reshape(b, kh, g, d).astype(jnp.float32)
-    scores = jnp.einsum("bkgd,btkd->bkgt", qg, kf) / math.sqrt(d)
+    scores = jnp.einsum("bkgd,bkdt->bkgt", qg, kf) / math.sqrt(d)
     slot = jnp.arange(T)[None, :]
     idx_last = (ci % T)[:, None]
     abs_pos = jnp.where(slot <= idx_last, ci[:, None] - idx_last + slot,
@@ -38,7 +44,7 @@ def flash_decode_ref(q, k_cache, v_cache, cache_index, *, window=None,
         valid &= abs_pos > ci[:, None] - window
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgt,btkd->bkgd", p, vf)
+    out = jnp.einsum("bkgt,bkdt->bkgd", p, vf)
     return out.reshape(b, h, d).astype(q.dtype)
 
 

@@ -75,9 +75,9 @@ def decode_cache_shapes(cfg: ModelConfig, batch: int, cache_len: int):
     dtype = jnp.dtype(cfg.dtype)
     if api.is_encdec(cfg):
         kv = jax.ShapeDtypeStruct(
-            (cfg.num_layers, batch, cache_len, cfg.num_kv_heads,
-             cfg.head_dim), dtype)
-        kv_ax = (P.LAYERS, P.BATCH, CACHE_SEQ, P.KV_HEADS, P.HEAD_DIM)
+            (cfg.num_layers, batch, cfg.num_kv_heads, cfg.head_dim,
+             cache_len), dtype)
+        kv_ax = (P.LAYERS, P.BATCH, P.KV_HEADS, P.HEAD_DIM, CACHE_SEQ)
         return {"k": kv, "v": kv}, {"k": kv_ax, "v": kv_ax}
     reps = cfg.pattern_repeats
     shapes, axes = {}, {}
@@ -85,16 +85,16 @@ def decode_cache_shapes(cfg: ModelConfig, batch: int, cache_len: int):
         if spec.mixer == "attn":
             kv_dtype = jnp.int8 if cfg.kv_quant else dtype
             kv = jax.ShapeDtypeStruct(
-                (reps, batch, cache_len, cfg.num_kv_heads, cfg.head_dim),
+                (reps, batch, cfg.num_kv_heads, cfg.head_dim, cache_len),
                 kv_dtype)
-            kv_ax = (P.LAYERS, P.BATCH, CACHE_SEQ, P.KV_HEADS, P.HEAD_DIM)
+            kv_ax = (P.LAYERS, P.BATCH, P.KV_HEADS, P.HEAD_DIM, CACHE_SEQ)
             shapes[f"pos{i}"] = {"k": kv, "v": kv}
             axes[f"pos{i}"] = {"k": kv_ax, "v": kv_ax}
             if cfg.kv_quant:
                 sc = jax.ShapeDtypeStruct(
-                    (reps, batch, cache_len, cfg.num_kv_heads),
+                    (reps, batch, cfg.num_kv_heads, cache_len),
                     jnp.float32)
-                sc_ax = (P.LAYERS, P.BATCH, CACHE_SEQ, P.KV_HEADS)
+                sc_ax = (P.LAYERS, P.BATCH, P.KV_HEADS, CACHE_SEQ)
                 shapes[f"pos{i}"]["k_scale"] = sc
                 shapes[f"pos{i}"]["v_scale"] = sc
                 axes[f"pos{i}"]["k_scale"] = sc_ax

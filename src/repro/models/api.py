@@ -7,6 +7,7 @@ All call sites (DiPaCo trainer, dry-run, serving, tests) go through:
   init_serve_cache(cfg, batch, cache_len)
   prefill(params, cfg, batch, cache_len) -> (logits, cache)
   serve_step(params, cfg, batch, cache, index) -> (logits, new_cache)
+  stack_paths(path_params_list)   -> params for serve_step(paths=P)
 
 ``serve_step`` (alias ``decode_step``) accepts a scalar index or a (B,)
 vector of per-row positions, so a continuous-batching engine can decode
@@ -76,16 +77,26 @@ def prefill(params, cfg: ModelConfig, batch, cache_len: int, *, window=None):
                       patch_embeds=batch.get("patch_embeds"))
 
 
-def serve_step(params, cfg: ModelConfig, batch, cache, index, *, window=None):
+def serve_step(params, cfg: ModelConfig, batch, cache, index, *, window=None,
+               mask=None, paths=None, row_offset=0):
     """One-token decode.  batch: dict(tokens (B,1) [+ enc_out and/or
-    precomputed cross_kv for enc-dec models])."""
+    precomputed cross_kv for enc-dec models]).  The cache is updated in
+    place (donate it).  Decoder LMs also take ``mask`` (rows to advance),
+    ``paths`` (params from :func:`stack_paths`, rows path-major) and
+    ``row_offset`` (token row b is cache row ``row_offset + b``): see
+    ``lm.decode_step``."""
     if is_encdec(cfg):
+        if mask is not None or paths is not None or row_offset != 0:
+            raise NotImplementedError(
+                "enc-dec decode takes no mask, stacked paths or row offset")
         return ED.decode_step_encdec(params, cfg, batch["tokens"],
                                      batch.get("enc_out"), cache, index,
                                      window=window,
                                      cross_kv=batch.get("cross_kv"))
     return LM.decode_step(params, cfg, batch["tokens"], cache, index,
-                          window=window)
+                          window=window, mask=mask, paths=paths,
+                          row_offset=row_offset)
 
 
 decode_step = serve_step
+stack_paths = LM.stack_paths

@@ -162,10 +162,10 @@ def apply_encdec(params, cfg: ModelConfig, tokens, frames, *, window=None):
 
 def init_encdec_cache(cfg: ModelConfig, batch: int, cache_len: int):
     dtype = jnp.dtype(cfg.dtype)
-    c = {"k": jnp.zeros((batch, cache_len, cfg.num_kv_heads, cfg.head_dim), dtype),
-         "v": jnp.zeros((batch, cache_len, cfg.num_kv_heads, cfg.head_dim), dtype)}
-    return jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x[None], (cfg.num_layers, *x.shape)), c)
+    # decoder self-attention ring caches, layer-stacked and tokens
+    # minor like the decoder LM's (lm.init_decode_cache)
+    kv = (cfg.num_layers, batch, cfg.num_kv_heads, cfg.head_dim, cache_len)
+    return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
 
 
 def build_cross_cache(params, cfg: ModelConfig, enc_out):

@@ -12,6 +12,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import NamedSharding, PartitionSpec
 
 from . import params as P
@@ -203,133 +204,199 @@ def chunked_attention(q, k, v, *, causal: bool, window: Optional[int],
     return out[:, :s].astype(q.dtype)
 
 
-def apply_attention(p, cfg: ModelConfig, x, *, positions, causal=True,
-                    window=None, cache=None, cache_index=None, kv_x=None):
-    """Multi-head attention with GQA/MQA, optional qk-norm & RoPE.
-
-    cache: optional dict(k=(B,T,KH,D), v=...) for decode/incremental
-    prefill; cache_index is the write position of the *first* token of
-    this call — an int32 scalar, or a (B,) vector when requests in the
-    batch sit at different positions (continuous batching).  Multi-token
-    calls (s > 1) write the block contiguously and mask causally within
-    it; the caller must ensure the block does not wrap the ring.
-    kv_x overrides key/value source (cross-attention; no RoPE, no causal
-    mask).  Returns (out, new_cache).
-    """
-    b, s, d_model = x.shape
-    cross = kv_x is not None
-    src = kv_x if cross else x
+def attention_qkv(p, cfg: ModelConfig, x, *, positions, kv_x=None):
+    """Projections, optional qk-norm and (self-attention only) RoPE:
+    q (B, S, H, D), k and v (B, Sk, KH, D)."""
+    src = x if kv_x is None else kv_x
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     k = jnp.einsum("bsd,dhk->bshk", src, p["wk"].astype(x.dtype))
     v = jnp.einsum("bsd,dhk->bshk", src, p["wv"].astype(x.dtype))
     if cfg.qk_norm:
         q = rms_norm(p["q_norm"], q, cfg.norm_eps)
         k = rms_norm(p["k_norm"], k, cfg.norm_eps)
-    if not cross:
+    if kv_x is None:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def attention_out(p, out, x):
+    """Output projection of (B, S, H, D) heads back to x's width."""
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype),
+                      out_sharding=_layout_of(x))
+
+
+def _quant_kv(x):
+    """int8 KV, per-(token, head) absmax scales (§Perf iteration N7:
+    halves the decode HBM traffic): x (..., KH, D) -> (int8 (..., KH, D),
+    f32 scales (..., KH))."""
+    xf = x.astype(jnp.float32)
+    scale = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
+                        / 127.0, 1e-8)
+    qx = jnp.clip(jnp.round(xf / scale), -127, 127).astype(jnp.int8)
+    return qx, scale[..., 0]
+
+
+def _cache_attention(cfg: ModelConfig, q, cache, ci, window):
+    """Dense (jnp) attention of the s tokens at positions ``ci .. ci+s-1``
+    (already written) over one layer's ring cache, tokens minor: k/v
+    (B, KH, D, T), int8 scales (B, KH, T).  q: (B, S, H, D) -> same."""
+    b, s = q.shape[:2]
+    ck, cv = cache["k"], cache["v"]
+    kh, T = ck.shape[1], ck.shape[3]
+    if "k_scale" in cache:
+        ck = (ck.astype(jnp.float32)
+              * cache["k_scale"][:, :, None, :]).astype(q.dtype)
+        cv = (cv.astype(jnp.float32)
+              * cache["v_scale"][:, :, None, :]).astype(q.dtype)
+    g = cfg.num_heads // kh
+    qg = q.reshape(b, s, kh, g, cfg.head_dim)
+    scores = (jnp.einsum("bqkgd,bkdt->bkgqt", qg, ck.astype(q.dtype),
+                         preferred_element_type=jnp.float32)
+              / math.sqrt(cfg.head_dim))
+    slot = jnp.arange(T)[None, :]                       # (1, T)
+    # absolute position stored in each ring slot, per batch row;
+    # reconstructed from the position of the *last* token written
+    last = ci + s - 1                                   # (B,)
+    idx_last = (last % T)[:, None]
+    abs_pos = jnp.where(slot <= idx_last,
+                        last[:, None] - idx_last + slot,
+                        last[:, None] - idx_last - T + slot)  # (B,T)
+    qpos = ci[:, None] + jnp.arange(s)[None, :]         # (B, S)
+    valid = ((abs_pos[:, None, :] >= 0)
+             & (abs_pos[:, None, :] <= qpos[..., None]))   # (B,S,T)
+    if window is not None:
+        valid &= abs_pos[:, None, :] > qpos[..., None] - window
+    scores = jnp.where(valid[:, None, None, :, :], scores, NEG_INF)
+    prob = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bkgqt,bkdt->bqkgd", prob, cv.astype(prob.dtype))
+    return out.reshape(b, s, cfg.num_heads, cfg.head_dim)
+
+
+def decode_attention_inplace(cfg: ModelConfig, q, k, v, cache, layer, ci, *,
+                             window=None, mask=None, row_offset=0):
+    """One decode token per row against a layer-stacked ring cache that
+    is updated in place.
+
+    q: (B, 1, H, D); k, v: (B, 1, KH, D); cache: dict k/v
+    (L, N, KH, D, T), tokens minor, plus int8 scales k_scale/v_scale
+    (L, N, KH, T); query row b is cache row ``row_offset + b``.  Each
+    row's token is written at ``(layer, row, :, :, ci % T)`` — where
+    ``mask`` (B,) is False, the value already held there, so the row's
+    cache stays bitwise as it was — and the rows then attend layer
+    ``layer``: ``flash_decode`` reads it in place, the jnp branch slices
+    the rows out.  Returns (out (B, 1, H, D), cache).
+    """
+    b = q.shape[0]
+    T = cache["k"].shape[-1]
+    slot = ci % T
+    new = {"k": k[:, 0], "v": v[:, 0]}                  # (B, KH, D)
+    if "k_scale" in cache:
+        new["k"], new["k_scale"] = _quant_kv(new["k"])
+        new["v"], new["v_scale"] = _quant_kv(new["v"])
+
+    def put(buf, val):
+        # one dynamic_update_slice per row: XLA keeps the carried buffer
+        # in the kernel's layout and updates it in place (a scatter of
+        # all rows at once makes it re-lay-out the whole arena around
+        # the kernel, for a D-minor scatter)
+        val = val.astype(buf.dtype)
+        for r in range(b):
+            at = ((layer, row_offset + r) + (0,) * (buf.ndim - 3)
+                  + (slot[r],))
+            tok = val[r][None, None, ..., None]
+            if mask is not None:
+                tok = jnp.where(mask[r], tok,
+                                jax.lax.dynamic_slice(buf, at, tok.shape))
+            buf = jax.lax.dynamic_update_slice(buf, tok, at)
+        return buf
+
+    cache = {name: put(buf, new[name]) for name, buf in cache.items()}
+    if cfg.attn_impl == "pallas":
+        from repro.kernels.ops import decode_attention as _pallas_decode
+        out = _pallas_decode(
+            q[:, 0], cache["k"], cache["v"], ci, layer,
+            row_offset=row_offset, window=window,
+            k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
+            interpret=cfg.pallas_interpret)
+        return out[:, None], cache
+    # hold the carried arena in the kernel's order here too: left free,
+    # XLA lays it out D-minor for the einsums and copies the whole arena
+    # in and out of the program (the einsums read one layer's rows)
+    cache = {name: with_layout_constraint(buf, Layout(tuple(range(buf.ndim))))
+             for name, buf in cache.items()}
+    mine = {name: jax.lax.dynamic_slice_in_dim(
+        jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False),
+        row_offset, b, 0) for name, buf in cache.items()}
+    return _cache_attention(cfg, q, mine, ci, window), cache
+
+
+def apply_attention(p, cfg: ModelConfig, x, *, positions, causal=True,
+                    window=None, cache=None, cache_index=None, kv_x=None):
+    """Multi-head attention with GQA/MQA, optional qk-norm & RoPE.
+
+    cache: optional dict of one layer's ring cache, tokens minor: k/v
+    (B, KH, D, T), and with ``cfg.kv_quant`` int8 k/v plus k_scale/v_scale
+    (B, KH, T).  cache_index is the write position of the *first* token
+    of this call — an int32 scalar, or a (B,) vector when requests in
+    the batch sit at different positions (continuous batching).
+    Multi-token calls (s > 1) write the block contiguously and mask
+    causally within it; the caller must ensure the block does not wrap
+    the ring.  Single-token calls go through
+    :func:`decode_attention_inplace` (the multi-path decode step calls it
+    directly on the whole layer-stacked cache).  kv_x overrides
+    key/value source (cross-attention; no RoPE, no causal mask).
+    Returns (out, new_cache).
+    """
+    b, s, d_model = x.shape
+    cross = kv_x is not None
+    q, k, v = attention_qkv(p, cfg, x, positions=positions, kv_x=kv_x)
     new_cache = None
     if cache is not None and not cross:
-        # decode / incremental: write k,v at cache_index (ring for windows)
-        T = cache["k"].shape[1]
+        T = cache["k"].shape[-1]
         ci = jnp.broadcast_to(
             jnp.asarray(cache_index, jnp.int32).reshape(-1), (b,))  # (B,)
-        if s > 1:
-            # multi-token (prefill) blocks are written contiguously — a
-            # block that wraps the ring would silently overwrite its own
-            # oldest entries, so reject it loudly while the start
-            # positions are still concrete (they are for every prefill
-            # call site: prefill always starts at 0 with s <= T).
-            if s > T:
+        if s == 1:
+            out, new_cache = decode_attention_inplace(
+                cfg, q, k, v, jax.tree_util.tree_map(lambda c: c[None],
+                                                     cache),
+                0, ci, window=window)
+            new_cache = jax.tree_util.tree_map(lambda c: c[0], new_cache)
+            return attention_out(p, out.astype(x.dtype), x), new_cache
+        # multi-token (prefill) blocks are written contiguously — a
+        # block that wraps the ring would silently overwrite its own
+        # oldest entries, so reject it loudly while the start positions
+        # are still concrete (they are for every prefill call site:
+        # prefill always starts at 0 with s <= T).
+        if s > T:
+            raise ValueError(
+                f"multi-token cache write of {s} tokens exceeds "
+                f"cache length {T}")
+        if not isinstance(ci, jax.core.Tracer):
+            starts = np.asarray(ci) % T
+            if int(starts.max()) + s > T:
                 raise ValueError(
-                    f"multi-token cache write of {s} tokens exceeds "
-                    f"cache length {T}")
-            if not isinstance(ci, jax.core.Tracer):
-                starts = np.asarray(ci) % T
-                if int(starts.max()) + s > T:
-                    raise ValueError(
-                        f"multi-token cache write wraps the ring: start "
-                        f"{int(starts.max())} + {s} tokens > cache "
-                        f"length {T}; split the block or grow the cache")
+                    f"multi-token cache write wraps the ring: start "
+                    f"{int(starts.max())} + {s} tokens > cache "
+                    f"length {T}; split the block or grow the cache")
         idx = ci % T
+        new = {"k": k, "v": v}                          # (B, S, KH, D)
+        if "k_scale" in cache:
+            new["k"], new["k_scale"] = _quant_kv(k)
+            new["v"], new["v_scale"] = _quant_kv(v)
 
-        def _row_update(buf, val, start):
-            """Per-row ring write: buf (B,T,...), val (B,s,...)."""
+        def _row_update(buf, val):
+            """Per-row ring write of s tokens: buf (B, ..., T), val
+            (B, S, ...) moved tokens-minor."""
+            val = jnp.moveaxis(val, 1, -1).astype(buf.dtype)
             return jax.vmap(
                 lambda c, x_, i: jax.lax.dynamic_update_slice(
-                    c, x_, (i,) + (0,) * (c.ndim - 1)))(buf, val, start)
+                    c, x_, (0,) * (c.ndim - 1) + (i,)))(buf, val, idx)
 
-        if "k_scale" in cache:
-            # int8 KV cache: per-(token, head) absmax scales — halves the
-            # decode HBM traffic (§Perf iteration N7)
-            def _quant(x):
-                xf = x.astype(jnp.float32)
-                scale = jnp.maximum(
-                    jnp.max(jnp.abs(xf), axis=-1, keepdims=True) / 127.0,
-                    1e-8)
-                qx = jnp.clip(jnp.round(xf / scale), -127, 127).astype(
-                    jnp.int8)
-                return qx, scale[..., 0]
-
-            kq, ks = _quant(k)
-            vq, vs = _quant(v)
-            ck = _row_update(cache["k"], kq, idx)
-            cv = _row_update(cache["v"], vq, idx)
-            cks = _row_update(cache["k_scale"], ks, idx)
-            cvs = _row_update(cache["v_scale"], vs, idx)
-            new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
-        else:
-            ck = _row_update(cache["k"], k.astype(cache["k"].dtype), idx)
-            cv = _row_update(cache["v"], v.astype(cache["v"].dtype), idx)
-            new_cache = {"k": ck, "v": cv}
-        if s == 1 and cfg.attn_impl == "pallas":
-            # NOTE (perf iteration #3, fused decode path): the jnp branch
-            # below materializes dense (B, H, S, T) scores over the whole
-            # ring cache — and, for int8 caches, an f32 copy of the full
-            # cache — every decode step.  The Pallas flash-decode kernel
-            # streams the cache block-by-block with online softmax,
-            # masks ring validity in-kernel from the per-row positions,
-            # and dequantizes int8 KV in VMEM, so decode HBM traffic is
-            # one pass over the (possibly int8) cache.
-            from repro.kernels.ops import decode_attention as _pallas_decode
-            out = _pallas_decode(
-                q[:, 0], ck, cv, ci, window=window,
-                k_scale=new_cache.get("k_scale"),
-                v_scale=new_cache.get("v_scale"),
-                interpret=cfg.pallas_interpret)
-            out = out[:, None].astype(x.dtype)              # (B, 1, H, D)
-        else:
-            if "k_scale" in new_cache:
-                ckf = (ck.astype(jnp.float32)
-                       * cks[..., None]).astype(q.dtype)
-                cvf = (cv.astype(jnp.float32)
-                       * cvs[..., None]).astype(q.dtype)
-            else:
-                ckf, cvf = ck, cv
-            # attend over valid cache entries
-            kh = ck.shape[2]
-            g = cfg.num_heads // kh
-            qg = q.reshape(b, s, kh, g, cfg.head_dim)
-            scores = (_gqa_scores(qg, ckf.astype(q.dtype))
-                      / math.sqrt(cfg.head_dim))
-            slot = jnp.arange(T)[None, :]                   # (1, T)
-            # absolute position stored in each ring slot, per batch row;
-            # reconstructed from the position of the *last* token written
-            last = ci + s - 1                               # (B,)
-            idx_last = (last % T)[:, None]
-            abs_pos = jnp.where(slot <= idx_last,
-                                last[:, None] - idx_last + slot,
-                                last[:, None] - idx_last - T + slot)  # (B,T)
-            qpos = ci[:, None] + jnp.arange(s)[None, :]     # (B, S)
-            valid = ((abs_pos[:, None, :] >= 0)
-                     & (abs_pos[:, None, :] <= qpos[..., None]))   # (B,S,T)
-            if window is not None:
-                valid &= abs_pos[:, None, :] > qpos[..., None] - window
-            scores = jnp.where(valid[:, None, None, :, :], scores, NEG_INF)
-            prob = jax.nn.softmax(scores, axis=-1)
-            out = _gqa_out(prob, cvf.astype(prob.dtype))
-            out = out.reshape(b, s, cfg.num_heads,
-                              cfg.head_dim).astype(x.dtype)
+        new_cache = {name: _row_update(buf, new[name])
+                     for name, buf in cache.items()}
+        out = _cache_attention(cfg, q, new_cache, ci, window)
+        out = out.astype(x.dtype)
     else:
         causal_eff = causal and not cross
         if cfg.attn_impl == "pallas" and causal_eff:
@@ -344,9 +411,7 @@ def apply_attention(p, cfg: ModelConfig, x, *, positions, causal=True,
                 q, k, v, causal=causal_eff, window=window,
                 chunk_q=cfg.attn_chunk_q, chunk_k=cfg.attn_chunk_k,
                 causal_skip=cfg.causal_skip)
-    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype),
-                   out_sharding=_layout_of(x))
-    return y, new_cache
+    return attention_out(p, out, x), new_cache
 
 
 # ---------------------------------------------------------------------------

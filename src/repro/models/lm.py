@@ -16,7 +16,8 @@ import jax.numpy as jnp
 
 from . import params as P
 from .config import ModelConfig
-from .layers import (apply_attention, apply_mlp, embed_tokens, init_attention,
+from .layers import (apply_attention, apply_mlp, attention_out, attention_qkv,
+                     decode_attention_inplace, embed_tokens, init_attention,
                      init_embedding, init_mlp, init_rmsnorm, rms_norm, unembed)
 from .moe_layer import apply_moe, init_moe
 from .ssm import apply_mamba, init_mamba, init_ssm_state
@@ -81,22 +82,21 @@ def init_lm(key, cfg: ModelConfig):
 # Apply
 # ---------------------------------------------------------------------------
 def _apply_block(bp, cfg: ModelConfig, spec, x, *, positions, window,
-                 cache=None, cache_index=None, is_prefill=False):
+                 cache=None, cache_index=None):
     aux = jnp.zeros((), jnp.float32)
     h = rms_norm(bp["norm1"], x, cfg.norm_eps)
     new_cache = None
     if spec.mixer == "attn":
-        attn_cache = None if cache is None else cache
         y, new_cache = apply_attention(
             bp["mixer"], cfg, h, positions=positions, causal=True,
-            window=window, cache=attn_cache, cache_index=cache_index)
-    elif is_prefill:
+            window=window, cache=cache, cache_index=cache_index)
+    elif cache is not None:
         # mamba prefill: full-sequence scan from a zero state; the
         # incoming (stale) slot state is overwritten, matching the
         # attention branch's write-from-position-0 semantics
         y, new_cache = apply_mamba(bp["mixer"], cfg, h, return_state=True)
-    else:  # mamba decode
-        y, new_cache = apply_mamba(bp["mixer"], cfg, h, state=cache)
+    else:
+        y, _ = apply_mamba(bp["mixer"], cfg, h)
     x = x + y
     if spec.mlp != "none":
         h = rms_norm(bp["norm2"], x, cfg.norm_eps)
@@ -110,9 +110,9 @@ def _apply_block(bp, cfg: ModelConfig, spec, x, *, positions, window,
 
 
 def _scan_blocks(params, cfg: ModelConfig, x, *, positions, window,
-                 caches=None, cache_index=None, is_prefill=False):
-    """Scan the repeating pattern group over ``pattern_repeats``."""
-    reps = cfg.pattern_repeats
+                 caches=None, cache_index=None):
+    """Scan the repeating pattern group over ``pattern_repeats`` (with
+    ``caches``: the prefill, which writes each layer's cache)."""
 
     def body(carry, xs):
         h, aux = carry
@@ -122,8 +122,7 @@ def _scan_blocks(params, cfg: ModelConfig, x, *, positions, window,
             c = None if bcaches is None else bcaches[f"pos{i}"]
             h, nc, a = _apply_block(
                 bparams[f"pos{i}"], cfg, spec, h, positions=positions,
-                window=window, cache=c, cache_index=cache_index,
-                is_prefill=is_prefill)
+                window=window, cache=c, cache_index=cache_index)
             aux = aux + a
             new_caches[f"pos{i}"] = nc
         if bcaches is None:
@@ -172,54 +171,149 @@ def apply_lm(params, cfg: ModelConfig, tokens, *, patch_embeds=None,
 # ---------------------------------------------------------------------------
 def init_decode_cache(cfg: ModelConfig, batch: int, cache_len: int,
                       dtype=None):
-    """Stacked caches matching the scan layout."""
+    """Layer-stacked caches, one dict per pattern position: attention
+    k/v ``(reps, batch, KH, D, T)``, tokens minor (the order
+    ``flash_decode`` reads in place), int8 scales ``(reps, batch, KH,
+    T)``; SSM state ``(reps, batch, ...)``.  The row axis is axis 1."""
     dtype = dtype or jnp.dtype(cfg.dtype)
     reps = cfg.pattern_repeats
+    kv = (reps, batch, cfg.num_kv_heads, cfg.head_dim, cache_len)
     caches = {}
     for i, spec in enumerate(cfg.pattern):
         if spec.mixer == "attn":
             if cfg.kv_quant:
-                c = {"k": jnp.zeros((batch, cache_len, cfg.num_kv_heads,
-                                     cfg.head_dim), jnp.int8),
-                     "v": jnp.zeros((batch, cache_len, cfg.num_kv_heads,
-                                     cfg.head_dim), jnp.int8),
-                     "k_scale": jnp.zeros(
-                         (batch, cache_len, cfg.num_kv_heads),
-                         jnp.float32),
-                     "v_scale": jnp.zeros(
-                         (batch, cache_len, cfg.num_kv_heads),
-                         jnp.float32)}
+                sc = kv[:3] + kv[4:]
+                c = {"k": jnp.zeros(kv, jnp.int8),
+                     "v": jnp.zeros(kv, jnp.int8),
+                     "k_scale": jnp.zeros(sc, jnp.float32),
+                     "v_scale": jnp.zeros(sc, jnp.float32)}
             else:
-                c = {"k": jnp.zeros((batch, cache_len, cfg.num_kv_heads,
-                                     cfg.head_dim), dtype),
-                     "v": jnp.zeros((batch, cache_len, cfg.num_kv_heads,
-                                     cfg.head_dim), dtype)}
+                c = {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
         else:
-            c = init_ssm_state(cfg, batch, dtype)
-        caches[f"pos{i}"] = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (reps, *x.shape)), c)
+            c = jax.tree_util.tree_map(
+                lambda x: jnp.broadcast_to(x[None], (reps, *x.shape)),
+                init_ssm_state(cfg, batch, dtype))
+        caches[f"pos{i}"] = c
     return caches
 
 
+def stack_paths(path_params_list):
+    """Stack homogeneous paths' parameters for :func:`decode_step`'s
+    ``paths``: block leaves layer-major ``(reps, P, ...)``, so one layer
+    of every path is a contiguous slice the layer loop reads in place;
+    every other leaf ``(P, ...)``."""
+    return {key: jax.tree_util.tree_map(
+        lambda *xs, axis=int(key == "blocks"): jnp.stack(xs, axis=axis),
+        *[p[key] for p in path_params_list])
+        for key in path_params_list[0]}
+
+
+def _decode_block(bp, cfg: ModelConfig, spec, x, cache, layer, *,
+                  positions, ci, mask, row_offset, window):
+    """One block of the decode step.  x: (P, S, 1, D), path-major rows;
+    bp: this layer's weights, leaves (P, ...); cache: this pattern
+    position's layer-stacked leaves (reps, N, ...), carried in place.
+    Matmuls run per path on its weights; attention runs over all P*S
+    rows at once."""
+    n_p, n_s = x.shape[:2]
+
+    def rows(t):
+        return t.reshape((n_p * n_s,) + t.shape[2:])
+
+    def per_path(t):
+        return t.reshape((n_p, n_s) + t.shape[1:])
+
+    def norm(scale, h):
+        return jax.vmap(lambda s_, h_: rms_norm(s_, h_, cfg.norm_eps))(
+            scale, h)
+
+    h = norm(bp["norm1"], x)
+    if spec.mixer == "attn":
+        q, k, v = jax.vmap(lambda p_, h_, pos_: attention_qkv(
+            p_, cfg, h_, positions=pos_))(bp["mixer"], h, positions)
+        out, cache = decode_attention_inplace(
+            cfg, rows(q), rows(k), rows(v), cache, layer, ci,
+            window=window, mask=mask, row_offset=row_offset)
+        y = jax.vmap(attention_out)(bp["mixer"],
+                                    per_path(out.astype(x.dtype)), h)
+    else:
+        # SSM state is per row and small: read this layer's rows, step,
+        # keep masked-off rows' state by a select, write the layer back
+        state = jax.tree_util.tree_map(
+            lambda c: jax.lax.dynamic_slice_in_dim(
+                c[layer], row_offset, n_p * n_s, 0), cache)
+        y, new = jax.vmap(lambda p_, h_, s_: apply_mamba(
+            p_, cfg, h_, state=s_))(
+                bp["mixer"], h, jax.tree_util.tree_map(per_path, state))
+        new = jax.tree_util.tree_map(rows, new)
+        if mask is not None:
+            new = jax.tree_util.tree_map(
+                lambda n, o: jnp.where(
+                    mask.reshape((-1,) + (1,) * (n.ndim - 1)),
+                    n.astype(o.dtype), o), new, state)
+        cache = jax.tree_util.tree_map(
+            lambda c, n: jax.lax.dynamic_update_slice(
+                c, n[None].astype(c.dtype),
+                (layer, row_offset) + (0,) * (c.ndim - 2)), cache, new)
+    x = x + y
+    if spec.mlp != "none":
+        h = norm(bp["norm2"], x)
+        if spec.mlp == "moe":
+            y = jax.vmap(lambda p_, h_: apply_moe(p_, cfg, h_)[0])(
+                bp["mlp"], h)
+        else:
+            y = jax.vmap(lambda p_, h_: apply_mlp(p_, cfg, h_))(
+                bp["mlp"], h)
+        x = x + y
+    return x, cache
+
+
 def decode_step(params, cfg: ModelConfig, tokens, caches, cache_index, *,
-                window=None):
+                window=None, mask=None, paths=None, row_offset=0):
     """One decode step.  tokens: (B, 1) -> (logits (B,1,V), new_caches).
 
     cache_index: int32 scalar, or a (B,) vector when the batch rows sit
     at different sequence positions (continuous batching over a slot
-    arena).
+    arena).  caches (:func:`init_decode_cache`) ride the layer loop's
+    carry and are updated in place (donate them): each layer writes one
+    token per row and ``flash_decode`` reads the layer where it lies.
+    Token row b is cache row ``row_offset + b``, so one island's rows can
+    decode against a larger arena.  ``mask`` (B,) bool: rows where it is
+    False keep their cache bitwise (their logits are junk).
+
+    ``paths``: None for one path's params; else ``params`` is
+    :func:`stack_paths` of that many paths and the rows are path-major,
+    row ``p * (B // paths) + s`` on path p — the stacked tick, one
+    dispatch for every island.
     """
-    x = _embed_inputs(params, cfg, tokens)
+    if paths is None:
+        params, paths = stack_paths([params]), 1
+    b = tokens.shape[0]
     ci = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32).reshape(-1),
-                          (tokens.shape[0],))
-    positions = ci[:, None]
+                          (b,))
+    tok = tokens.reshape(paths, b // paths, 1)
+    x = jax.vmap(lambda e, t: embed_tokens(e, cfg, t))(params["embed"], tok)
+    positions = ci.reshape(paths, b // paths, 1)
     window = window if window is not None else cfg.sliding_window
-    x, aux, new_caches = _scan_blocks(
-        params, cfg, x, positions=positions, window=window,
-        caches=caches, cache_index=ci)
-    x = rms_norm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], cfg, x)
-    return logits, new_caches
+
+    def body(carry, xs):
+        h, cs = carry
+        layer, bparams = xs
+        cs = dict(cs)
+        for i, spec in enumerate(cfg.pattern):
+            h, cs[f"pos{i}"] = _decode_block(
+                bparams[f"pos{i}"], cfg, spec, h, cs[f"pos{i}"], layer,
+                positions=positions, ci=ci, mask=mask,
+                row_offset=row_offset, window=window)
+        return (h, cs), None
+
+    (x, caches), _ = jax.lax.scan(
+        body, (x, caches),
+        (jnp.arange(cfg.pattern_repeats), params["blocks"]))
+    logits = jax.vmap(lambda fn, e, h: unembed(
+        e, cfg, rms_norm(fn, h, cfg.norm_eps)))(
+            params["final_norm"], params["embed"], x)
+    return logits.reshape(b, 1, -1), caches
 
 
 def prefill(params, cfg: ModelConfig, tokens, cache_len: int, *,
@@ -241,7 +335,7 @@ def prefill(params, cfg: ModelConfig, tokens, cache_len: int, *,
     window = window if window is not None else cfg.sliding_window
     x, aux, new_caches = _scan_blocks(
         params, cfg, x, positions=positions, window=window,
-        caches=caches, cache_index=jnp.int32(0), is_prefill=True)
+        caches=caches, cache_index=jnp.int32(0))
     x = rms_norm(params["final_norm"], x, cfg.norm_eps)
     logits = unembed(params["embed"], cfg, x)
     return logits, new_caches

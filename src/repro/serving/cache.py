@@ -2,8 +2,10 @@
 
 One :class:`SlotArena` per path island (paper §2.2/§2.6: paths are
 instantiated and served independently).  The arena holds a single
-decode-cache pytree whose leading axis is ``num_slots``; a request
-occupies one slot row from admission to completion.  Allocation and
+layer-stacked decode-cache pytree (``api.init_serve_cache``: attention
+k/v ``(reps, num_slots, KH, D, T)``, tokens minor) whose row axis, axis
+1, is the slot; a request occupies one slot row from admission to
+completion.  Allocation and
 free are O(1) host-side bookkeeping — cache buffers are written in
 place (row scatter), never rebuilt per request.
 
@@ -24,6 +26,7 @@ against one-forward prefill).
 """
 from __future__ import annotations
 
+import functools
 from collections import OrderedDict
 from typing import Optional, Tuple
 
@@ -39,6 +42,24 @@ class SlotExhausted(Exception):
     """Raised by :meth:`SlotArena.alloc` when no slot is free."""
 
 
+# the arena buffers are donated: row writes update in place instead of
+# copying the whole pool every admission
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(arena, rows, slots):
+    """Write row i of the batch-R cache ``rows`` into arena row
+    ``slots[i]``; leaves are layer-stacked ``(reps, rows, ...)``, the
+    row axis is axis 1 (rows beyond ``len(slots)`` are ignored)."""
+    def one(a, r):
+        def body(i, acc):
+            row = jax.lax.dynamic_index_in_dim(r, i, axis=1, keepdims=True)
+            return jax.lax.dynamic_update_slice(
+                acc, row.astype(acc.dtype),
+                (0, slots[i]) + (0,) * (acc.ndim - 2))
+        return jax.lax.fori_loop(0, slots.shape[0], body, a)
+    return jax.tree_util.tree_map(one, arena, rows)
+
+
+
 class SlotArena:
     """Fixed-size pool of per-request cache slots for one path island."""
 
@@ -48,28 +69,10 @@ class SlotArena:
         self.cache_len = cache_len
         self.cache = api.init_serve_cache(cfg, num_slots, cache_len)
         self._free = list(range(num_slots - 1, -1, -1))
-        # per-slot next write position; parked at 0 while free so idle
-        # arena rows scribble only on position 0 (overwritten by the
-        # next prefill) during full-width decode ticks
+        # per-slot next write position; parked at 0 while free (decode
+        # ticks leave free rows' caches untouched: their mask is False)
         self.positions = np.zeros(num_slots, np.int32)
         self.active = np.zeros(num_slots, bool)
-
-        def _write_rows(arena, rows, slots):
-            # cache leaves are layer-stacked: (reps, batch, ...) — the
-            # request/slot axis is axis 1
-            def one(a, r):
-                def body(i, acc):
-                    row = jax.lax.dynamic_index_in_dim(
-                        r, i, axis=1, keepdims=True)
-                    return jax.lax.dynamic_update_slice(
-                        acc, row.astype(acc.dtype),
-                        (0, slots[i]) + (0,) * (acc.ndim - 2))
-                return jax.lax.fori_loop(0, slots.shape[0], body, a)
-            return jax.tree_util.tree_map(one, arena, rows)
-
-        # the arena buffers are donated: row scatters update in place
-        # instead of copying the whole pool every admission
-        self._write_rows = jax.jit(_write_rows, donate_argnums=0)
 
     # -- bookkeeping ---------------------------------------------------
     @property
@@ -106,8 +109,7 @@ class SlotArena:
         (the next decode index for that request).
         """
         slots = np.asarray(slots, np.int32)
-        self.cache = self._write_rows(self.cache, sub_cache,
-                                      jnp.asarray(slots))
+        self.cache = _write_rows(self.cache, sub_cache, jnp.asarray(slots))
         for s, p in zip(slots, np.asarray(positions, np.int32)):
             self.positions[s] = p
 
@@ -190,16 +192,21 @@ class StackedSlotArenas:
     """Joint slot arenas for ``num_paths`` homogeneous path islands.
 
     All paths of a DiPaCo deployment share one architecture, so their
-    decode caches can live in a single pytree whose leaves carry a
-    leading path axis ``(P, reps, num_slots, ...)``.  One vmapped decode
-    dispatch then advances *every* island per tick (the stacked-island
-    tick) instead of one jit call per island from a Python loop — per
-    Pathways, dispatch overhead rather than FLOPs dominates the
-    many-small-islands regime.
+    decode caches live in one pytree with the islands folded into the
+    row axis: attention k/v ``(reps, P * S, KH, D, T)`` — layer outermost,
+    tokens minor — where row ``p * S + s`` is slot ``s`` of path ``p``
+    (int8 scales ``(reps, P * S, KH, T)``, SSM state ``(reps, P * S,
+    ...)``).  One decode dispatch then advances *every* island per tick
+    (the stacked-island tick) instead of one jit call per island from a
+    Python loop — per Pathways, dispatch overhead rather than FLOPs
+    dominates the many-small-islands regime — and it reads each layer in
+    place and writes one token per row, since the layer loop carries
+    the arena in exactly this order.
 
     Host-side bookkeeping (free lists, positions, active flags) stays
-    per path; :meth:`view` exposes a :class:`SlotArena`-shaped facade
-    per island so engine/test code is agnostic to the backing layout.
+    per path, ``(P, S)``; :meth:`view` exposes a :class:`SlotArena`-shaped
+    facade per island so engine/test code is agnostic to the backing
+    layout.
     """
 
     def __init__(self, cfg: ModelConfig, num_paths: int, num_slots: int,
@@ -208,30 +215,13 @@ class StackedSlotArenas:
         self.num_paths = num_paths
         self.num_slots = num_slots
         self.cache_len = cache_len
-        one = api.init_serve_cache(cfg, num_slots, cache_len)
-        self.cache = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (num_paths, *x.shape)), one)
+        self.cache = api.init_serve_cache(cfg, num_paths * num_slots,
+                                          cache_len)
         self._free = [list(range(num_slots - 1, -1, -1))
                       for _ in range(num_paths)]
         self.positions = np.zeros((num_paths, num_slots), np.int32)
         self.active = np.zeros((num_paths, num_slots), bool)
         self.views = [_StackedArenaView(self, p) for p in range(num_paths)]
-
-        def _write_rows(arena, rows, path, slots):
-            # arena leaves: (P, reps, slots, ...); rows: (reps, R, ...)
-            def one_leaf(a, r):
-                def body(i, acc):
-                    row = jax.lax.dynamic_index_in_dim(
-                        r, i, axis=1, keepdims=True)
-                    return jax.lax.dynamic_update_slice(
-                        acc, row[None].astype(acc.dtype),
-                        (path, 0, slots[i]) + (0,) * (acc.ndim - 3))
-                return jax.lax.fori_loop(0, slots.shape[0], body, a)
-            return jax.tree_util.tree_map(one_leaf, arena, rows)
-
-        # donation is essential here: without it every admission write
-        # would copy the caches of ALL islands, not just the target row
-        self._write_rows = jax.jit(_write_rows, donate_argnums=0)
 
     # -- per-path bookkeeping (mirrors SlotArena) ----------------------
     def num_free(self, path: int) -> int:
@@ -258,8 +248,8 @@ class StackedSlotArenas:
         ``path`` (R may be smaller than the sub-cache batch: padded
         bucket rows beyond R are ignored)."""
         slots = np.asarray(slots, np.int32)
-        self.cache = self._write_rows(self.cache, sub_cache,
-                                      jnp.int32(path), jnp.asarray(slots))
+        self.cache = _write_rows(self.cache, sub_cache,
+                                 jnp.asarray(path * self.num_slots + slots))
         for s, p in zip(slots, np.asarray(positions, np.int32)):
             self.positions[path, s] = p
 
@@ -283,7 +273,8 @@ class _StackedArenaView:
     @property
     def cache(self):
         """This island's cache rows (gathered; for tests/inspection)."""
-        return jax.tree_util.tree_map(lambda x: x[self.path],
+        lo = self.path * self.num_slots
+        return jax.tree_util.tree_map(lambda x: x[:, lo:lo + self.num_slots],
                                       self._stacked.cache)
 
     def alloc(self) -> int:

@@ -73,6 +73,23 @@ def _paths_homogeneous(path_params_list) -> bool:
     return True
 
 
+def decode_program(cfg: ModelConfig, paths: Optional[int] = None):
+    """The masked decode step the continuous engine dispatches, jitted
+    with the cache donated (every caller rebinds its cache reference to
+    the returned pytree).  ``paths=None`` decodes one island's rows on
+    its own params; ``paths=P`` is the stacked tick: params from
+    ``api.stack_paths``, tokens, positions and mask ``(P * S,)``
+    path-major, against the row-folded arena — one dispatch advances
+    every island, touching the KV arena only by ``flash_decode``'s read
+    and one masked token write per row and layer."""
+    def _decode_one(params, tok, cache, idx, mask):
+        logits, cache = api.serve_step(params, cfg, {"tokens": tok}, cache,
+                                       idx, mask=mask, paths=paths)
+        return logits[:, 0], cache
+
+    return jax.jit(_decode_one, donate_argnums=2)
+
+
 def _default_buckets(cache_len: int):
     """Power-of-two prompt-length buckets, capped at cache_len."""
     buckets, b = [], 16
@@ -365,11 +382,12 @@ class ContinuousBatchingEngine(_EngineBase):
     prefilling admissions in length-bucketed batched forwards (prompts
     padded up to a small fixed bucket set, so the compile cache is
     bounded by the buckets, not the admission pattern); (2) decode every
-    in-flight request of *all* islands in one stacked vmapped dispatch —
-    path params are stacked along a leading axis and the masked decode
-    step is vmapped over it (rows that were prefilled this tick, or are
-    free, keep their cache untouched); (3) emit one greedy token per
-    request, retiring finished requests and migrating re-routed ones.
+    in-flight request of *all* islands in one stacked dispatch — path
+    params are stacked layer-major, the islands' slots are rows of one
+    arena, and the layer loop runs every path's weights on its own rows
+    (rows that were prefilled this tick, or are free, keep their cache
+    untouched); (3) emit one greedy token per request, retiring finished
+    requests and migrating re-routed ones.
 
     ``stacked=False`` falls back to one jit call per island (required
     for heterogeneous path architectures, where params cannot stack);
@@ -416,8 +434,7 @@ class ContinuousBatchingEngine(_EngineBase):
         # warmed, bounded compile set instead of an exact-length compile
         self.prefill_buckets = tuple(sorted(set(buckets) | {cache_len}))
         if self.stacked:
-            self._stacked_params = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *path_params_list)
+            self._stacked_params = api.stack_paths(path_params_list)
             self._stacked_arenas = StackedSlotArenas(
                 cfg, num_paths, slots_per_path, cache_len)
             self.arenas = self._stacked_arenas.views
@@ -472,46 +489,25 @@ class ContinuousBatchingEngine(_EngineBase):
         # whole extension machinery costs one jit entry
         self._extend = jax.jit(_extend_one, donate_argnums=2)
 
-        def _decode_one(params, tok, cache, idx, mask):
-            logits, new_cache = api.serve_step(
-                params, cfg_, {"tokens": tok}, cache, idx)
-
-            def sel(new, old):
-                m = mask.reshape((1, -1) + (1,) * (new.ndim - 2))
-                return jnp.where(m, new.astype(old.dtype), old)
-
-            new_cache = jax.tree_util.tree_map(sel, new_cache, cache)
-            return logits[:, 0], new_cache
-
-        # caches are donated (in-place decode); every caller rebinds
-        # its cache reference to the returned pytree
-        self._decode_masked = jax.jit(_decode_one, donate_argnums=2)
+        self._decode_masked = decode_program(cfg)
         # stacked-island tick: one dispatch advances every island
-        self._decode_stacked = jax.jit(jax.vmap(_decode_one),
-                                       donate_argnums=2)
+        self._decode_stacked = decode_program(cfg, num_paths)
 
-        def _decode_island(params, path, tok, stacked_cache, idx, mask):
-            """Single-island decode against the stacked arena: slice the
-            island's cache rows out, decode, scatter them back in place
-            (donation).  Used by the hybrid tick when few islands have
-            work — a full stacked dispatch would burn (P-k)/P of its
-            compute on empty islands."""
-            cache_p = jax.tree_util.tree_map(
-                lambda x: jax.lax.dynamic_index_in_dim(
-                    x, path, axis=0, keepdims=False), stacked_cache)
-            logits, new_cache = _decode_one(params, tok, cache_p, idx,
-                                            mask)
-            new_stacked = jax.tree_util.tree_map(
-                lambda full, new: jax.lax.dynamic_update_index_in_dim(
-                    full, new.astype(full.dtype), path, axis=0),
-                stacked_cache, new_cache)
-            return logits, new_stacked
+        def _decode_island(params, row0, tok, stacked_cache, idx, mask):
+            """Single-island decode against the stacked arena, in place:
+            the island's rows start at ``row0``.  Used by the hybrid tick
+            when few islands have work — a full stacked dispatch would
+            burn (P-k)/P of its compute on empty islands."""
+            logits, cache = api.serve_step(
+                params, cfg_, {"tokens": tok}, stacked_cache, idx,
+                mask=mask, row_offset=row0)
+            return logits[:, 0], cache
 
         self._decode_island = jax.jit(_decode_island, donate_argnums=3)
 
         def _restack(old, *new):
-            return jax.tree_util.tree_map(
-                lambda o, *ns: jnp.stack(ns).astype(o.dtype), old, *new)
+            return jax.tree_util.tree_map(lambda o, n: n.astype(o.dtype),
+                                          old, api.stack_paths(new))
 
         # hot-swap double-buffering: the outgoing stacked tree is
         # donated, so XLA reuses its buffers for the incoming stack
@@ -649,14 +645,15 @@ class ContinuousBatchingEngine(_EngineBase):
                             jnp.full((rows,), length - 1, jnp.int32))
         if self.stacked:
             sa = self._stacked_arenas
-            tok = jnp.zeros((sa.num_paths, sa.num_slots, 1), jnp.int32)
-            mask = jnp.zeros((sa.num_paths, sa.num_slots), bool)
+            rows = sa.num_paths * sa.num_slots
+            tok = jnp.zeros((rows, 1), jnp.int32)
+            mask = jnp.zeros((rows,), bool)
             _, sa.cache = self._decode_stacked(
                 self._stacked_params, tok, sa.cache,
-                jnp.asarray(sa.positions), mask)   # mask=False: no-op
+                jnp.asarray(sa.positions.reshape(-1)), mask)  # no-op
             _, sa.cache = self._decode_island(
-                self.paths[0], jnp.int32(0), tok[0], sa.cache,
-                jnp.asarray(sa.positions[0]), mask[0])
+                self.paths[0], jnp.int32(0), tok[:sa.num_slots], sa.cache,
+                jnp.asarray(sa.positions[0]), mask[:sa.num_slots])
             # warm the hot-swap install too: the swap contract is "no
             # compile inside a serving tick", which must include the
             # first swap's restack dispatch
@@ -888,8 +885,8 @@ class ContinuousBatchingEngine(_EngineBase):
     def _decode_tick(self) -> None:
         """Advance every in-flight request one token.
 
-        Stacked mode: ONE vmapped dispatch decodes the full
-        (paths, slots) arena — per-island dispatch overhead is paid
+        Stacked mode: ONE dispatch decodes the full (paths x slots)
+        arena — per-island dispatch overhead is paid
         once per tick, not once per island.  Fallback: one masked
         full-arena decode step per island with work.
         """
@@ -935,22 +932,25 @@ class ContinuousBatchingEngine(_EngineBase):
             tok[st.path, st.slot, 0] = st.tokens[-1]
             mask[st.path, st.slot] = True
         if dense:
-            # dense tick: one vmapped dispatch advances every island
+            # dense tick: one dispatch advances every island; the arena's
+            # rows are path-major, so (P, S) arrays fold to P * S rows
             logits, sa.cache = self._decode_stacked(
-                self._stacked_params, jnp.asarray(tok), sa.cache,
-                jnp.asarray(sa.positions), jnp.asarray(mask))
-            logits = self._fetch(logits)
+                self._stacked_params, jnp.asarray(tok.reshape(-1, 1)),
+                sa.cache, jnp.asarray(sa.positions.reshape(-1)),
+                jnp.asarray(mask.reshape(-1)))
+            logits = self._fetch(logits).reshape(
+                sa.num_paths, sa.num_slots, -1)
             for st in rows:
                 st.next_logits = logits[st.path, st.slot]
             return
         # sparse tick (e.g. trace drain): decode only the active
-        # islands, slicing their rows in/out of the stacked arena
+        # islands, each on its own rows of the stacked arena in place
         out = {}
         for p in active:
             lg, sa.cache = self._decode_island(
-                self.paths[p], jnp.int32(p), jnp.asarray(tok[p]),
-                sa.cache, jnp.asarray(sa.positions[p]),
-                jnp.asarray(mask[p]))
+                self.paths[p], jnp.int32(p * sa.num_slots),
+                jnp.asarray(tok[p]), sa.cache,
+                jnp.asarray(sa.positions[p]), jnp.asarray(mask[p]))
             out[p] = self._fetch(lg)
         for st in rows:
             st.next_logits = out[st.path][st.slot]

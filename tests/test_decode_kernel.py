@@ -1,6 +1,7 @@
 """Flash-decode kernel parity: Pallas (interpret mode) vs the dense
 ref.py oracle vs the model's jnp ring-cache branch — GQA group sizes,
-ring wrap-around, sliding windows, int8 KV, per-row (B,) positions."""
+ring wrap-around, sliding windows, int8 KV, per-row (B,) positions, and
+the in-place read of one layer's rows out of a layer-stacked cache."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,12 +11,25 @@ from repro.kernels import ops, ref
 from repro.kernels.decode_attention import flash_decode
 
 
+# the kernel reads layer LAYER, rows ROW0.. of a (LAYERS, b + ROW0 + 1,
+# KH, D, T) stack; the oracle gets that slice alone, so a wrong index
+# map reads other layers' or rows' keys and fails the comparison
+LAYERS, LAYER, ROW0 = 3, 1, 1
+
+
 def _setup(key, b, h, kh, d, T, dtype=jnp.float32):
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (b, h, d)).astype(dtype)
-    kc = jax.random.normal(ks[1], (b, T, kh, d)).astype(dtype)
-    vc = jax.random.normal(ks[2], (b, T, kh, d)).astype(dtype)
+    kc = jax.random.normal(ks[1], (LAYERS, b + ROW0 + 1, kh, d, T)
+                           ).astype(dtype)
+    vc = jax.random.normal(ks[2], (LAYERS, b + ROW0 + 1, kh, d, T)
+                           ).astype(dtype)
     return q, kc, vc
+
+
+def _mine(c, b):
+    """The kernel's slice of a stacked cache, as a one-layer stack."""
+    return c[LAYER:LAYER + 1, ROW0:ROW0 + b]
 
 
 @pytest.mark.parametrize("b,h,kh,d,T,ci,window,block_k", [
@@ -30,9 +44,10 @@ def _setup(key, b, h, kh, d, T, dtype=jnp.float32):
 def test_flash_decode_vs_ref(b, h, kh, d, T, ci, window, block_k):
     q, kc, vc = _setup(jax.random.PRNGKey(0), b, h, kh, d, T)
     ci = jnp.asarray(ci, jnp.int32)
-    out = flash_decode(q, kc, vc, ci, window=window, block_k=block_k,
-                       interpret=True)
-    expect = ref.flash_decode_ref(q, kc, vc, ci, window=window)
+    out = flash_decode(q, kc, vc, ci, LAYER, row_offset=ROW0, window=window,
+                       block_k=block_k, interpret=True)
+    expect = ref.flash_decode_ref(q, _mine(kc, b), _mine(vc, b), ci,
+                                  window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-5, rtol=1e-5)
 
@@ -42,8 +57,9 @@ def test_flash_decode_vs_ref(b, h, kh, d, T, ci, window, block_k):
 def test_flash_decode_dtypes(dtype, tol):
     q, kc, vc = _setup(jax.random.PRNGKey(1), 2, 8, 4, 64, 32, dtype)
     ci = jnp.asarray([9, 27], jnp.int32)
-    out = ops.decode_attention(q, kc, vc, ci, block_k=16, interpret=True)
-    expect = ref.flash_decode_ref(q, kc, vc, ci)
+    out = ops.decode_attention(q, kc, vc, ci, LAYER, row_offset=ROW0,
+                               block_k=16, interpret=True)
+    expect = ref.flash_decode_ref(q, _mine(kc, 2), _mine(vc, 2), ci)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32),
                                atol=tol, rtol=tol)
@@ -55,18 +71,20 @@ def test_flash_decode_int8_kv(window):
     b, h, kh, d, T = 2, 4, 2, 32, 32
     q, kc, vc = _setup(jax.random.PRNGKey(2), b, h, kh, d, T)
 
-    def quant(x):
-        scale = jnp.maximum(jnp.max(jnp.abs(x), axis=-1) / 127.0, 1e-8)
-        qx = jnp.clip(jnp.round(x / scale[..., None]), -127, 127)
+    def quant(x):          # per-(token, head) scales over D (axis -2)
+        scale = jnp.maximum(jnp.max(jnp.abs(x), axis=-2) / 127.0, 1e-8)
+        qx = jnp.clip(jnp.round(x / scale[..., None, :]), -127, 127)
         return qx.astype(jnp.int8), scale
 
     kq, ks = quant(kc)
     vq, vs = quant(vc)
     ci = jnp.asarray([6, 50], jnp.int32)
-    out = ops.decode_attention(q, kq, vq, ci, window=window, k_scale=ks,
-                               v_scale=vs, block_k=8, interpret=True)
-    expect = ref.flash_decode_ref(q, kq, vq, ci, window=window,
-                                  k_scale=ks, v_scale=vs)
+    out = ops.decode_attention(q, kq, vq, ci, LAYER, row_offset=ROW0,
+                               window=window, k_scale=ks, v_scale=vs,
+                               block_k=8, interpret=True)
+    expect = ref.flash_decode_ref(
+        q, _mine(kq, b), _mine(vq, b), ci, window=window,
+        k_scale=_mine(ks, b), v_scale=_mine(vs, b))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-5, rtol=1e-5)
 
@@ -111,13 +129,14 @@ def test_kernel_matches_jnp_cache_branch(kv_quant, window):
 
 
 def test_decode_under_vmap():
-    """The kernel batches correctly under vmap (the stacked-island
-    decode dispatch vmaps the whole decode step over a path axis)."""
+    """The kernel batches correctly under vmap (the stacked-worker
+    decode of ``launch.steps.make_decode_step`` vmaps the whole decode
+    step over a worker axis)."""
     P, b, h, kh, d, T = 2, 3, 4, 2, 32, 24
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
     q = jax.random.normal(ks[0], (P, b, h, d))
-    kc = jax.random.normal(ks[1], (P, b, T, kh, d))
-    vc = jax.random.normal(ks[2], (P, b, T, kh, d))
+    kc = jax.random.normal(ks[1], (P, 1, b, kh, d, T))
+    vc = jax.random.normal(ks[2], (P, 1, b, kh, d, T))
     ci = jnp.asarray([[0, 10, 30], [5, 23, 47]], jnp.int32)
     f = jax.vmap(lambda q_, k_, v_, c_: flash_decode(
         q_, k_, v_, c_, block_k=8, interpret=True))

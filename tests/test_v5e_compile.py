@@ -12,7 +12,9 @@ The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every
 test worker imports every test file.  Keep these tests in this one file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +25,7 @@ from repro.configs import get_config
 from repro.kernels.decode_attention import flash_decode
 from repro.kernels.ops import flash_attention_trainable
 from repro.models import api
+from repro.serving.engine import decode_program
 
 HEADS, HEAD_DIM, SEQ = 16, 64, 2048      # dipaco-150m: 16 x 64, T <= 2048
 V5E_HBM = 16 * 2**30
@@ -68,19 +71,23 @@ def test_flash_decode_compiles(one_chip, kv):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    b = 8
+    # 8 rows at a traced offset into a 3-layer, 16-row stacked cache,
+    # read at a traced layer
+    b, layers, rows = 8, 3, 16
     q = s((b, HEADS, HEAD_DIM), jnp.bfloat16)
     ci = s((b,), jnp.int32)
+    at = s((), jnp.int32)
     if kv == "bf16":
-        cache = s((b, SEQ, HEADS, HEAD_DIM), jnp.bfloat16)
-        _compile(lambda q, k, v, ci: flash_decode(q, k, v, ci),
-                 q, cache, cache, ci)
+        cache = s((layers, rows, HEADS, HEAD_DIM, SEQ), jnp.bfloat16)
+        _compile(lambda q, k, v, ci, layer, row0: flash_decode(
+            q, k, v, ci, layer, row_offset=row0),
+            q, cache, cache, ci, at, at)
     else:
-        cache = s((b, SEQ, HEADS, HEAD_DIM), jnp.int8)
-        scale = s((b, SEQ, HEADS), jnp.float32)
-        _compile(lambda q, k, v, ci, ks, vs: flash_decode(
-            q, k, v, ci, k_scale=ks, v_scale=vs),
-            q, cache, cache, ci, scale, scale)
+        cache = s((layers, rows, HEADS, HEAD_DIM, SEQ), jnp.int8)
+        scale = s((layers, rows, HEADS, SEQ), jnp.float32)
+        _compile(lambda q, k, v, ci, layer, row0, ks, vs: flash_decode(
+            q, k, v, ci, layer, row_offset=row0, k_scale=ks, v_scale=vs),
+            q, cache, cache, ci, at, at, scale, scale)
 
 
 def test_flash_attention_forward_compiles(one_chip):
@@ -129,3 +136,57 @@ def test_full_width_decode_step_fits_v5e(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < V5E_HBM, mem
+
+
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s32": 4,
+          "u32": 4, "f32": 4}
+
+
+def _largest_result(hlo: str, opcodes) -> tuple:
+    """The largest result, in bytes, of an instruction with one of
+    ``opcodes`` anywhere in the optimized HLO (fusion bodies included,
+    so a fused whole-arena select counts too)."""
+    found = (0, "")
+    for m in re.finditer(r"%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(",
+                         hlo):
+        name, dtype, dims, op = m.groups()
+        if op in opcodes:
+            size = _BYTES.get(dtype, 4) * math.prod(
+                int(d) for d in dims.split(",") if d)
+            found = max(found, (size, name))
+    return found
+
+
+def test_engine_stacked_tick_is_in_place(one_chip):
+    """The stacked decode tick ``ContinuousBatchingEngine`` dispatches
+    (``engine.decode_program``), at ``dipaco-150m`` widths, 8 paths x 4
+    slots x 1024 bf16 cache with ``flash_decode``: the KV arena is read
+    in place by the kernel and written one token per row, so the program
+    needs under 1 GB beside its arguments, and no select, copy or
+    dynamic-slice produces as much as one layer's K for all rows."""
+    cfg = get_config("dipaco-150m").replace(attn_impl="pallas")
+    paths, slots, cache_len = 8, 4, 1024
+    rows = paths * slots
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    one = jax.eval_shape(
+        lambda: api.init_model(jax.random.PRNGKey(0), cfg)[0])
+    params = place(jax.eval_shape(api.stack_paths, [one] * paths))
+    cache = place(jax.eval_shape(
+        lambda: api.init_serve_cache(cfg, rows, cache_len)))
+    vec = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    compiled = decode_program(cfg, paths).lower(
+        params, jax.ShapeDtypeStruct((rows, 1), jnp.int32, sharding=one_chip),
+        cache, vec, jax.ShapeDtypeStruct((rows,), bool, sharding=one_chip),
+    ).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1e9, mem
+    layer_kv = rows * cfg.num_kv_heads * cfg.head_dim * cache_len * 2
+    size, name = _largest_result(hlo, ("select", "copy", "dynamic-slice"))
+    assert size < layer_kv, (name, size, layer_kv)
