@@ -18,7 +18,7 @@ ASSIGNED_ARCHS = [
     "pixtral-12b",
     "qwen3-8b",
     "qwen2-moe-a2.7b",
-    "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b",
     "nemotron-4-340b",
 ]
 

@@ -14,7 +14,9 @@ import functools
 import jax
 
 from .decode_attention import flash_decode as _flash_decode
+from .mla_decode import mla_decode as _mla_decode
 from .moe_gmm import expert_gemm as _gemm
+from .moe_gmm import grouped_matmul as _grouped
 from .router_assign import router_assign as _assign
 from .ssd_scan import ssd_scan as _ssd
 
@@ -33,6 +35,25 @@ def decode_attention(q, k_cache, v_cache, cache_index, layer=0, *,
                          row_offset=row_offset, window=window,
                          k_scale=k_scale, v_scale=v_scale, block_k=block_k,
                          interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("kv_rank", "scale", "block_k",
+                                             "interpret"))
+def mla_decode(q, lat_cache, cache_index, layer=0, *, kv_rank, scale,
+               row_offset=0, block_k=512, interpret=False):
+    """Latent decode attention: one token per row, absorbed MLA over one
+    layer of the layer-stacked ring of latents ``(L, N, C, T)``, read in
+    place.  q: (B, H, C) float32; returns (B, H, kv_rank) float32."""
+    return _mla_decode(q, lat_cache, cache_index, layer, kv_rank=kv_rank,
+                       scale=scale, row_offset=row_offset, block_k=block_k,
+                       interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def grouped_matmul(x, w, group_sizes, *, interpret=False):
+    """Dropless grouped matmul over rows sorted by group: x (M, K), w
+    (G, K, N), group_sizes (G,) -> (M, N)."""
+    return _grouped(x, w, group_sizes, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",

@@ -32,6 +32,27 @@ def attn_flops_per_token(cfg: ModelConfig, s_kv: float) -> float:
     return proj + scores
 
 
+def mla_flops_per_token(cfg: ModelConfig, s_kv: float,
+                        absorbed: bool = False) -> float:
+    """Multi-head latent attention: the q projection, the latent and
+    rotary key, the output projection, and attention over ``s_kv``
+    positions.  Published (non-absorbed) form, for training and prefill:
+    each position's keys and values expanded per head from the latent,
+    scores over the nope+rope width, values of v width.  Absorbed form,
+    for decode: q_nope folded into the latent and the output out of it,
+    once per token; scores and the weighted sum over the latent (+ rope)
+    width."""
+    m, d, h = cfg.mla, cfg.d_model, cfg.num_heads
+    r, dn, dr, dv = (m.kv_lora_rank, m.qk_nope_head_dim,
+                     m.qk_rope_head_dim, m.v_head_dim)
+    proj = 2 * d * h * (dn + dr) + 2 * d * (r + dr) + 2 * h * dv * d
+    if absorbed:
+        fold = 2 * h * dn * r + 2 * h * r * dv
+        return proj + fold + 2 * s_kv * h * ((r + dr) + r)
+    expand = 2 * r * h * (dn + dv)
+    return proj + expand + 2 * s_kv * h * ((dn + dr) + dv)
+
+
 def mlp_flops_per_token(cfg: ModelConfig, d_ff: int) -> float:
     nmat = 3 if cfg.mlp_type in ("swiglu", "geglu") else 2
     return 2 * nmat * cfg.d_model * d_ff
@@ -43,7 +64,9 @@ def moe_flops_per_token(cfg: ModelConfig, tokens_per_group: float) -> float:
     router = 2 * d * m.num_experts
     nmat = 3 if cfg.mlp_type in ("swiglu", "geglu") else 2
     expert = 2 * nmat * d * m.d_ff_expert * m.top_k
-    if m.impl == "dense":
+    if m.impl == "dropless":
+        dispatch = 0.0                       # sorted rows: memory traffic
+    elif m.impl == "dense":
         # GShard dispatch+combine einsums: 2 x (2 * E*C * d) per token,
         # E*C = g*k*cf
         ec = tokens_per_group * m.top_k * m.capacity_factor
@@ -78,14 +101,17 @@ def ssm_flops_per_token(cfg: ModelConfig) -> float:
 
 
 def block_flops_per_token(cfg: ModelConfig, spec, s_kv: float,
-                          tokens_per_group: float) -> float:
+                          tokens_per_group: float, d_ff=None,
+                          absorbed: bool = False) -> float:
     f = 0.0
-    if spec.mixer == "attn":
+    if spec.mixer == "mla":
+        f += mla_flops_per_token(cfg, s_kv, absorbed)
+    elif spec.mixer == "attn":
         f += attn_flops_per_token(cfg, s_kv)
     else:
         f += ssm_flops_per_token(cfg)
     if spec.mlp == "dense":
-        f += mlp_flops_per_token(cfg, cfg.d_ff)
+        f += mlp_flops_per_token(cfg, d_ff or cfg.d_ff)
     elif spec.mlp == "moe":
         f += moe_flops_per_token(cfg, tokens_per_group)
     return f
@@ -119,15 +145,21 @@ def analyze(cfg: ModelConfig, shape: InputShape, *,
     reps = cfg.pattern_repeats
     per_tok = 0.0
     bd = {"attn": 0.0, "mlp": 0.0, "moe": 0.0, "ssm": 0.0}
-    for spec in cfg.pattern:
-        if spec.mixer == "attn":
-            bd["attn"] += attn_flops_per_token(cfg, s_kv) * reps
+    layers = [(spec, reps, cfg.d_ff) for spec in cfg.pattern]
+    if cfg.first_dense:
+        layers.append((cfg.lead_spec, cfg.first_dense, cfg.d_ff_dense))
+    for spec, n, d_ff in layers:
+        if spec.mixer == "mla":
+            bd["attn"] += mla_flops_per_token(
+                cfg, s_kv, absorbed=shape.kind == "decode") * n
+        elif spec.mixer == "attn":
+            bd["attn"] += attn_flops_per_token(cfg, s_kv) * n
         else:
-            bd["ssm"] += ssm_flops_per_token(cfg) * reps
+            bd["ssm"] += ssm_flops_per_token(cfg) * n
         if spec.mlp == "dense":
-            bd["mlp"] += mlp_flops_per_token(cfg, cfg.d_ff) * reps
+            bd["mlp"] += mlp_flops_per_token(cfg, d_ff) * n
         elif spec.mlp == "moe":
-            bd["moe"] += moe_flops_per_token(cfg, tokens_per_group) * reps
+            bd["moe"] += moe_flops_per_token(cfg, tokens_per_group) * n
     per_tok = sum(bd.values())
     unembed = 2 * cfg.d_model * cfg.vocab_size
     bd["unembed"] = unembed
@@ -215,7 +247,13 @@ def _bytes_model(cfg: ModelConfig, shape: InputShape, tokens: int,
 def _act_bytes(cfg: ModelConfig, tokens: int, s_kv: float, dt: int) -> float:
     d = cfg.d_model
     per_layer_tok = 12 * d * dt              # residual stream traffic
-    if any(s.mixer == "attn" for s in cfg.pattern):
+    if cfg.mla is not None:
+        # keys and values expanded from the latent, re-read per q-chunk
+        m = cfg.mla
+        nq = max(1.0, s_kv / cfg.attn_chunk_q / 2)
+        per_layer_tok += cfg.num_heads * (m.qk_head_dim + m.v_head_dim) \
+            * dt * nq
+    elif any(s.mixer == "attn" for s in cfg.pattern):
         # chunked attention re-reads K/V once per q-chunk
         nq = max(1.0, s_kv / cfg.attn_chunk_q / 2)
         per_layer_tok += 2 * cfg.num_kv_heads * cfg.head_dim * dt * nq
@@ -229,7 +267,10 @@ def _cache_bytes(cfg: ModelConfig, shape: InputShape, dt: int) -> float:
     total = 0.0
     reps = cfg.pattern_repeats
     for spec in cfg.pattern:
-        if spec.mixer == "attn":
+        if spec.mixer == "mla":
+            # one latent + rotary key per token and layer, bf16
+            total += B * L * cfg.mla.latent_dim * dt * cfg.num_layers
+        elif spec.mixer == "attn":
             # int8 KV cache: 1 byte/elem + f32 scale per (token, head)
             kv_bytes = (1.0 + 4.0 / cfg.head_dim) if cfg.kv_quant else dt
             total += (2 * B * L * cfg.num_kv_heads * cfg.head_dim

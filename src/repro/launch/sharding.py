@@ -30,6 +30,7 @@ DEFAULT_RULES: dict[str, tuple] = {
     P.SSM_INNER: ("model",),
     # never sharded:
     P.LAYERS: (), P.EMBED: (), P.HEAD_DIM: (), P.SEQ: (), P.CONV: (),
+    P.LATENT: (),
     P.SSM_STATE: (), None: (),
 }
 

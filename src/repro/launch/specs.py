@@ -79,40 +79,46 @@ def decode_cache_shapes(cfg: ModelConfig, batch: int, cache_len: int):
              cache_len), dtype)
         kv_ax = (P.LAYERS, P.BATCH, P.KV_HEADS, P.HEAD_DIM, CACHE_SEQ)
         return {"k": kv, "v": kv}, {"k": kv_ax, "v": kv_ax}
-    reps = cfg.pattern_repeats
-    shapes, axes = {}, {}
-    for i, spec in enumerate(cfg.pattern):
+
+    def stacked(spec, n):
+        if spec.mixer == "mla":
+            return ({"lat": jax.ShapeDtypeStruct(
+                (n, batch, cfg.mla.latent_dim, cache_len), dtype)},
+                {"lat": (P.LAYERS, P.BATCH, P.LATENT, CACHE_SEQ)})
         if spec.mixer == "attn":
             kv_dtype = jnp.int8 if cfg.kv_quant else dtype
             kv = jax.ShapeDtypeStruct(
-                (reps, batch, cfg.num_kv_heads, cfg.head_dim, cache_len),
+                (n, batch, cfg.num_kv_heads, cfg.head_dim, cache_len),
                 kv_dtype)
             kv_ax = (P.LAYERS, P.BATCH, P.KV_HEADS, P.HEAD_DIM, CACHE_SEQ)
-            shapes[f"pos{i}"] = {"k": kv, "v": kv}
-            axes[f"pos{i}"] = {"k": kv_ax, "v": kv_ax}
+            shapes, axes = {"k": kv, "v": kv}, {"k": kv_ax, "v": kv_ax}
             if cfg.kv_quant:
                 sc = jax.ShapeDtypeStruct(
-                    (reps, batch, cfg.num_kv_heads, cache_len),
-                    jnp.float32)
+                    (n, batch, cfg.num_kv_heads, cache_len), jnp.float32)
                 sc_ax = (P.LAYERS, P.BATCH, P.KV_HEADS, CACHE_SEQ)
-                shapes[f"pos{i}"]["k_scale"] = sc
-                shapes[f"pos{i}"]["v_scale"] = sc
-                axes[f"pos{i}"]["k_scale"] = sc_ax
-                axes[f"pos{i}"]["v_scale"] = sc_ax
-        else:
-            from repro.models.ssm import ssm_dims
-            d_inner, n_heads, conv_dim = ssm_dims(cfg)
-            shapes[f"pos{i}"] = {
-                "conv": jax.ShapeDtypeStruct(
-                    (reps, batch, cfg.ssm.conv_width - 1, conv_dim), dtype),
-                "ssm": jax.ShapeDtypeStruct(
-                    (reps, batch, n_heads, cfg.ssm.head_dim,
-                     cfg.ssm.d_state), jnp.float32),
-            }
-            axes[f"pos{i}"] = {
-                "conv": (P.LAYERS, P.BATCH, P.CONV, P.SSM_INNER),
-                "ssm": (P.LAYERS, P.BATCH, P.HEADS, P.HEAD_DIM, P.SSM_STATE),
-            }
+                shapes.update(k_scale=sc, v_scale=sc)
+                axes.update(k_scale=sc_ax, v_scale=sc_ax)
+            return shapes, axes
+        from repro.models.ssm import ssm_dims
+        d_inner, n_heads, conv_dim = ssm_dims(cfg)
+        return ({
+            "conv": jax.ShapeDtypeStruct(
+                (n, batch, cfg.ssm.conv_width - 1, conv_dim), dtype),
+            "ssm": jax.ShapeDtypeStruct(
+                (n, batch, n_heads, cfg.ssm.head_dim, cfg.ssm.d_state),
+                jnp.float32),
+        }, {
+            "conv": (P.LAYERS, P.BATCH, P.CONV, P.SSM_INNER),
+            "ssm": (P.LAYERS, P.BATCH, P.HEADS, P.HEAD_DIM, P.SSM_STATE),
+        })
+
+    shapes, axes = {}, {}
+    for i, spec in enumerate(cfg.pattern):
+        shapes[f"pos{i}"], axes[f"pos{i}"] = stacked(spec,
+                                                     cfg.pattern_repeats)
+    if cfg.first_dense:
+        shapes["lead"], axes["lead"] = stacked(cfg.lead_spec,
+                                               cfg.first_dense)
     return shapes, axes
 
 

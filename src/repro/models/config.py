@@ -14,8 +14,17 @@ class MoEConfig:
     num_shared: int = 0            # always-active shared experts
     d_ff_shared: int = 0           # total shared-expert hidden size
     capacity_factor: float = 1.25
-    impl: str = "dense"            # "dense" (GShard one-hot) | "scatter" (sorted buckets)
+    # "dense" (GShard one-hot) | "scatter" (sorted buckets), both with a
+    # capacity; "dropless" (sorted by expert, grouped matmuls, no drops)
+    impl: str = "dense"
     router_aux_weight: float = 0.01  # load-balance auxiliary loss
+    # "softmax": softmax over the experts, top-k, renormalised.
+    # "sigmoid": DeepSeek-V3's noaux_tc router: sigmoid scores; a
+    # per-expert bias (a parameter) is added to choose the top-k but is
+    # not part of the gates; the chosen scores are normalised and scaled
+    # by ``routed_scaling``
+    router: str = "softmax"
+    routed_scaling: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -31,9 +40,31 @@ class SSMConfig:
 
 
 @dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3).  A token's keys and
+    values come from one latent of ``kv_lora_rank`` (normalised) plus one
+    rotary key of ``qk_rope_head_dim`` shared by all heads; queries come
+    straight from the hidden state (``q_lora_rank`` null)."""
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one token's cache entry: the latent, then the rotary
+        key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
 class BlockSpec:
     """One layer's composition: a token mixer plus a channel mixer."""
-    mixer: str                     # "attn" | "mamba"
+    mixer: str                     # "attn" | "mla" | "mamba"
     mlp: str                       # "dense" | "moe" | "none"
 
 
@@ -67,6 +98,11 @@ class ModelConfig:
     mlp_type: str = "swiglu"       # swiglu | geglu | relu2 | gelu
     pattern: tuple = (BlockSpec("attn", "dense"),)
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
+    # leading layers ahead of the pattern: the pattern's mixer with a
+    # dense MLP of width ``d_ff_dense`` (DeepSeek's first_k_dense_replace)
+    first_dense: int = 0
+    d_ff_dense: int = 0
     ssm: Optional[SSMConfig] = None
     encoder: Optional[EncoderConfig] = None
     vision: Optional[VisionStubConfig] = None
@@ -92,10 +128,17 @@ class ModelConfig:
 
     @property
     def pattern_repeats(self) -> int:
-        assert self.num_layers % len(self.pattern) == 0, (
-            f"{self.name}: num_layers={self.num_layers} not divisible by "
-            f"pattern length {len(self.pattern)}")
-        return self.num_layers // len(self.pattern)
+        n = self.num_layers - self.first_dense
+        assert n % len(self.pattern) == 0, (
+            f"{self.name}: {n} layers after the {self.first_dense} leading "
+            f"dense ones not divisible by pattern length "
+            f"{len(self.pattern)}")
+        return n // len(self.pattern)
+
+    @property
+    def lead_spec(self) -> BlockSpec:
+        """The leading dense layers' composition."""
+        return BlockSpec(self.pattern[0].mixer, "dense")
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

@@ -102,7 +102,8 @@ def _gqa_out(p, v):
 
 def full_attention(q, k, v, *, causal: bool, window: Optional[int],
                    q_offset: int = 0):
-    """Reference O(S^2)-memory attention.  q: (B,Sq,H,D), k/v: (B,Sk,KH,D)."""
+    """Reference O(S^2)-memory attention.  q: (B,Sq,H,D), k: (B,Sk,KH,D),
+    v: (B,Sk,KH,Dv); scores scaled by 1/sqrt(D)."""
     b, sq, h, d = q.shape
     sk, kh = k.shape[1], k.shape[2]
     g = h // kh
@@ -118,20 +119,21 @@ def full_attention(q, k, v, *, causal: bool, window: Optional[int],
     scores = jnp.where(mask, scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
     out = _gqa_out(p, v)
-    return out.reshape(b, sq, h, d).astype(q.dtype)
+    return out.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
 
 
 def chunked_attention(q, k, v, *, causal: bool, window: Optional[int],
                       chunk_q: int = 512, chunk_k: int = 512,
                       causal_skip: bool = False):
     """Online-softmax blockwise attention; O(S*chunk) activation memory.
+    q, k: (B, S, H|KH, D); v: (B, S, KH, Dv).
 
     With ``causal_skip`` the fully-masked (future) key chunks are
     structurally skipped (flops ~ S^2/2 instead of S^2), and with a
     window also the fully-expired past chunks are skipped.
     """
     b, s, h, d = q.shape
-    kh = k.shape[2]
+    kh, dv = k.shape[2], v.shape[-1]
     g = h // kh
     nq = -(-s // chunk_q)
     pad_q = nq * chunk_q - s
@@ -145,7 +147,7 @@ def chunked_attention(q, k, v, *, causal: bool, window: Optional[int],
     sk_pad = nk * chunk_k
     qc = q.reshape(b, nq, chunk_q, kh, g, d).astype(jnp.float32)
     kc = k.reshape(b, nk, chunk_k, kh, d).astype(jnp.float32)
-    vc = v.reshape(b, nk, chunk_k, kh, d).astype(jnp.float32)
+    vc = v.reshape(b, nk, chunk_k, kh, dv).astype(jnp.float32)
     scale = 1.0 / math.sqrt(d)
     kpos_all = jnp.arange(sk_pad).reshape(nk, chunk_k)
     valid_k = kpos_all < (sk_pad - pad_k)
@@ -173,7 +175,7 @@ def chunked_attention(q, k, v, *, causal: bool, window: Optional[int],
         qi = qc[:, i_static]
         m0 = jnp.full((b, kh, g, chunk_q), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, kh, g, chunk_q), jnp.float32)
-        a0 = jnp.zeros((b, kh, g, chunk_q, d), jnp.float32)
+        a0 = jnp.zeros((b, kh, g, chunk_q, dv), jnp.float32)
         if causal_skip:
             lo = 0
             if window is not None:
@@ -199,8 +201,8 @@ def chunked_attention(q, k, v, *, causal: bool, window: Optional[int],
     else:
         out = jax.lax.map(lambda i: q_block(i), jnp.arange(nq))  # (nq,b,kh,g,cq,d)
         out = jnp.moveaxis(out, 0, 3)
-    out = out.reshape(b, kh, g, nq * chunk_q, d)
-    out = jnp.moveaxis(out, 3, 1).reshape(b, nq * chunk_q, kh * g, d)
+    out = out.reshape(b, kh, g, nq * chunk_q, dv)
+    out = jnp.moveaxis(out, 3, 1).reshape(b, nq * chunk_q, kh * g, dv)
     return out[:, :s].astype(q.dtype)
 
 
@@ -237,6 +239,36 @@ def _quant_kv(x):
     return qx, scale[..., 0]
 
 
+def _slot_positions(last, T: int):
+    """The absolute position each ring slot holds, per row: (B, T), from
+    each row's position of the *last* token written, ``last`` (B,);
+    negative where nothing was written yet."""
+    slot = jnp.arange(T)[None, :]
+    idx_last = (last % T)[:, None]
+    return jnp.where(slot <= idx_last, last[:, None] - idx_last + slot,
+                     last[:, None] - idx_last - T + slot)
+
+
+def _put_tokens(buf, val, layer, row_offset, slot, mask):
+    """Write row r's token ``val[r]`` into a layer-stacked, tokens-minor
+    buffer ``(L, N, ..., T)`` at ``(layer, row_offset + r, ..., slot[r])``;
+    where ``mask`` (B,) is False, the value already there.
+
+    One dynamic_update_slice per row: XLA keeps the carried buffer in
+    the kernel's layout and updates it in place (a scatter of all rows
+    at once makes it re-lay-out the whole arena around the kernel, for a
+    D-minor scatter)."""
+    val = val.astype(buf.dtype)
+    for r in range(val.shape[0]):
+        at = (layer, row_offset + r) + (0,) * (buf.ndim - 3) + (slot[r],)
+        tok = val[r][None, None, ..., None]
+        if mask is not None:
+            tok = jnp.where(mask[r], tok,
+                            jax.lax.dynamic_slice(buf, at, tok.shape))
+        buf = jax.lax.dynamic_update_slice(buf, tok, at)
+    return buf
+
+
 def _cache_attention(cfg: ModelConfig, q, cache, ci, window):
     """Dense (jnp) attention of the s tokens at positions ``ci .. ci+s-1``
     (already written) over one layer's ring cache, tokens minor: k/v
@@ -254,14 +286,7 @@ def _cache_attention(cfg: ModelConfig, q, cache, ci, window):
     scores = (jnp.einsum("bqkgd,bkdt->bkgqt", qg, ck.astype(q.dtype),
                          preferred_element_type=jnp.float32)
               / math.sqrt(cfg.head_dim))
-    slot = jnp.arange(T)[None, :]                       # (1, T)
-    # absolute position stored in each ring slot, per batch row;
-    # reconstructed from the position of the *last* token written
-    last = ci + s - 1                                   # (B,)
-    idx_last = (last % T)[:, None]
-    abs_pos = jnp.where(slot <= idx_last,
-                        last[:, None] - idx_last + slot,
-                        last[:, None] - idx_last - T + slot)  # (B,T)
+    abs_pos = _slot_positions(ci + s - 1, T)            # (B, T)
     qpos = ci[:, None] + jnp.arange(s)[None, :]         # (B, S)
     valid = ((abs_pos[:, None, :] >= 0)
              & (abs_pos[:, None, :] <= qpos[..., None]))   # (B,S,T)
@@ -287,31 +312,14 @@ def decode_attention_inplace(cfg: ModelConfig, q, k, v, cache, layer, ci, *,
     ``layer``: ``flash_decode`` reads it in place, the jnp branch slices
     the rows out.  Returns (out (B, 1, H, D), cache).
     """
-    b = q.shape[0]
     T = cache["k"].shape[-1]
     slot = ci % T
     new = {"k": k[:, 0], "v": v[:, 0]}                  # (B, KH, D)
     if "k_scale" in cache:
         new["k"], new["k_scale"] = _quant_kv(new["k"])
         new["v"], new["v_scale"] = _quant_kv(new["v"])
-
-    def put(buf, val):
-        # one dynamic_update_slice per row: XLA keeps the carried buffer
-        # in the kernel's layout and updates it in place (a scatter of
-        # all rows at once makes it re-lay-out the whole arena around
-        # the kernel, for a D-minor scatter)
-        val = val.astype(buf.dtype)
-        for r in range(b):
-            at = ((layer, row_offset + r) + (0,) * (buf.ndim - 3)
-                  + (slot[r],))
-            tok = val[r][None, None, ..., None]
-            if mask is not None:
-                tok = jnp.where(mask[r], tok,
-                                jax.lax.dynamic_slice(buf, at, tok.shape))
-            buf = jax.lax.dynamic_update_slice(buf, tok, at)
-        return buf
-
-    cache = {name: put(buf, new[name]) for name, buf in cache.items()}
+    cache = {name: _put_tokens(buf, new[name], layer, row_offset, slot,
+                               mask) for name, buf in cache.items()}
     if cfg.attn_impl == "pallas":
         from repro.kernels.ops import decode_attention as _pallas_decode
         out = _pallas_decode(
@@ -327,7 +335,7 @@ def decode_attention_inplace(cfg: ModelConfig, q, k, v, cache, layer, ci, *,
              for name, buf in cache.items()}
     mine = {name: jax.lax.dynamic_slice_in_dim(
         jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False),
-        row_offset, b, 0) for name, buf in cache.items()}
+        row_offset, q.shape[0], 0) for name, buf in cache.items()}
     return _cache_attention(cfg, q, mine, ci, window), cache
 
 
@@ -412,6 +420,147 @@ def apply_attention(p, cfg: ModelConfig, x, *, positions, causal=True,
                 chunk_q=cfg.attn_chunk_q, chunk_k=cfg.attn_chunk_k,
                 causal_skip=cfg.causal_skip)
     return attention_out(p, out, x), new_cache
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (MLA, DeepSeek-V2/V3)
+# ---------------------------------------------------------------------------
+def init_mla(key, cfg: ModelConfig):
+    """q: one projection to H heads of (nope + rope) width; the keys and
+    values of a token come from ``w_dkv``: a latent of ``kv_lora_rank``
+    (RMS-normalised by ``kv_norm``), expanded per head by ``w_uk`` and
+    ``w_uv``, and one rotary key shared by all heads."""
+    m, d, h = cfg.mla, cfg.d_model, cfg.num_heads
+    r = m.kv_lora_rank
+    ks = jax.random.split(key, 5)
+    p = {
+        "wq": jax.random.normal(ks[0], (d, h, m.qk_head_dim)) / math.sqrt(d),
+        "w_dkv": jax.random.normal(ks[1], (d, m.latent_dim)) / math.sqrt(d),
+        "kv_norm": jnp.ones((r,)),
+        "w_uk": jax.random.normal(ks[2], (r, h, m.qk_nope_head_dim))
+        / math.sqrt(r),
+        "w_uv": jax.random.normal(ks[3], (r, h, m.v_head_dim)) / math.sqrt(r),
+        "wo": jax.random.normal(ks[4], (h, m.v_head_dim, d))
+        / math.sqrt(h * m.v_head_dim),
+    }
+    a = {
+        "wq": (P.EMBED, P.HEADS, P.HEAD_DIM),
+        "w_dkv": (P.EMBED, P.LATENT),
+        "kv_norm": (P.LATENT,),
+        "w_uk": (P.LATENT, P.HEADS, P.HEAD_DIM),
+        "w_uv": (P.LATENT, P.HEADS, P.HEAD_DIM),
+        "wo": (P.HEADS, P.HEAD_DIM, P.EMBED),
+    }
+    return p, a
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: 1/sqrt of the query-key width (nope + rope)."""
+    return 1.0 / math.sqrt(cfg.mla.qk_head_dim)
+
+
+def _mla_q_latent(p, cfg: ModelConfig, x, positions):
+    """Queries, split: q_nope (B, S, H, dn) and roped q_pe (B, S, H, dr);
+    and each token's cache entry (B, S, r + dr): the normalised latent,
+    then the roped shared key."""
+    m = cfg.mla
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+    q_nope, q_pe = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
+    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    kv = jnp.einsum("bsd,dc->bsc", x, p["w_dkv"].astype(x.dtype))
+    c = rms_norm(p["kv_norm"], kv[..., :m.kv_lora_rank], cfg.norm_eps)
+    k_pe = apply_rope(kv[..., None, m.kv_lora_rank:], positions,
+                      cfg.rope_theta)[..., 0, :]
+    return q_nope, q_pe, jnp.concatenate([c, k_pe], axis=-1)
+
+
+def apply_mla(p, cfg: ModelConfig, x, *, positions, window=None):
+    """The published (non-absorbed) form, for training and prefill: keys
+    and values expanded from the latent, attention within the block
+    (causal from its first token, which is position 0 in a prefill).
+    x: (B, S, d) -> (out (B, S, d), cache entries (B, S, r + dr))."""
+    m, h = cfg.mla, cfg.num_heads
+    q_nope, q_pe, lat = _mla_q_latent(p, cfg, x, positions)
+    c, k_pe = lat[..., :m.kv_lora_rank], lat[..., m.kv_lora_rank:]
+    k_nope = jnp.einsum("bsc,chk->bshk", c, p["w_uk"].astype(x.dtype))
+    v = jnp.einsum("bsc,chk->bshk", c, p["w_uv"].astype(x.dtype))
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_pe[:, :, None, :], k_nope.shape[:3] + (m.qk_rope_head_dim,))], -1)
+    s = x.shape[1]
+    if s <= cfg.attn_chunk_q:
+        out = full_attention(q, k, v, causal=True, window=window)
+    else:
+        out = chunked_attention(q, k, v, causal=True, window=window,
+                                chunk_q=cfg.attn_chunk_q,
+                                chunk_k=cfg.attn_chunk_k, causal_skip=True)
+    y = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype),
+                   p["wo"].astype(x.dtype), out_sharding=_layout_of(x))
+    return y, lat
+
+
+def mla_decode_q(p, cfg: ModelConfig, x, positions):
+    """The absorbed form's queries for one token per row: q_nope folded
+    through ``w_uk`` into the latent, beside the roped q_pe: (B, 1, H,
+    r + dr) in float32; and the token's cache entry (B, 1, r + dr)."""
+    q_nope, q_pe, lat = _mla_q_latent(p, cfg, x, positions)
+    q_lat = jnp.einsum("bshk,chk->bshc", q_nope, p["w_uk"].astype(x.dtype),
+                       preferred_element_type=jnp.float32)
+    return jnp.concatenate([q_lat, q_pe.astype(jnp.float32)], -1), lat
+
+
+def mla_decode_out(p, out_lat, x):
+    """Attention output in the latent (B, 1, H, r) -> (B, 1, d): through
+    ``w_uv`` to the heads' values, then ``wo``."""
+    o = jnp.einsum("bshc,chk->bshk", out_lat.astype(x.dtype),
+                   p["w_uv"].astype(x.dtype))
+    return jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(x.dtype))
+
+
+def _latent_attention(cfg: ModelConfig, q, lat, ci):
+    """jnp absorbed attention of one token per row over its rows' ring
+    of latents.  q: (B, H, r + dr) float32; lat: (B, r + dr, T) ->
+    (B, H, r) float32."""
+    r, T = cfg.mla.kv_lora_rank, lat.shape[-1]
+    lat = lat.astype(jnp.float32)
+    scores = jnp.einsum("bhc,bct->bht", q, lat) * mla_scale(cfg)
+    pos = _slot_positions(ci, T)
+    valid = (pos >= 0) & (pos <= ci[:, None])
+    scores = jnp.where(valid[:, None, :], scores, NEG_INF)
+    prob = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bht,bct->bhc", prob, lat[:, :r])
+
+
+def mla_attention_inplace(cfg: ModelConfig, q, lat, cache, layer, ci, *,
+                          mask=None, row_offset=0):
+    """One decode token per row, absorbed, against the layer-stacked
+    latent arena, updated in place.
+
+    q: (B, 1, H, r + dr) from :func:`mla_decode_q`; lat: (B, 1, r + dr),
+    the token's cache entry; cache: ``{"lat": (L, N, r + dr, T)}``,
+    tokens minor.  The entry is written at ``(layer, row_offset + b, :,
+    ci % T)`` (the old value where ``mask`` is False); then scores are
+    ``q_lat . c + q_pe . k_pe`` over the row's valid ring slots and the
+    output is the probabilities' sum of latents: ``mla_decode`` reads the
+    layer in place, the jnp branch slices the rows out.  Returns (out
+    (B, 1, H, r) float32, cache)."""
+    buf = cache["lat"]
+    T = buf.shape[-1]
+    buf = _put_tokens(buf, lat[:, 0], layer, row_offset, ci % T, mask)
+    if cfg.attn_impl == "pallas":
+        from repro.kernels.ops import mla_decode
+        out = mla_decode(q[:, 0], buf, ci, layer, row_offset=row_offset,
+                         kv_rank=cfg.mla.kv_lora_rank,
+                         scale=mla_scale(cfg),
+                         interpret=cfg.pallas_interpret)
+    else:
+        # kept in the kernel's order, as in decode_attention_inplace
+        buf = with_layout_constraint(buf, Layout(tuple(range(buf.ndim))))
+        mine = jax.lax.dynamic_slice_in_dim(
+            jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False),
+            row_offset, q.shape[0], 0)
+        out = _latent_attention(cfg, q[:, 0], mine, ci)
+    return out[:, None], {"lat": buf}
 
 
 # ---------------------------------------------------------------------------

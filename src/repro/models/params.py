@@ -32,6 +32,7 @@ MLP = "mlp"
 VOCAB = "vocab"
 EXPERT = "expert"
 EXPERT_MLP = "expert_mlp"
+LATENT = "latent"       # MLA key/value latent and rotary key
 SSM_INNER = "ssm_inner"
 SSM_STATE = "ssm_state"
 CONV = "conv"

@@ -3,9 +3,10 @@
 One :class:`SlotArena` per path island (paper §2.2/§2.6: paths are
 instantiated and served independently).  The arena holds a single
 layer-stacked decode-cache pytree (``api.init_serve_cache``: attention
-k/v ``(reps, num_slots, KH, D, T)``, tokens minor) whose row axis, axis
-1, is the slot; a request occupies one slot row from admission to
-completion.  Allocation and
+k/v ``(reps, num_slots, KH, D, T)``, or for latent attention one
+``(reps, num_slots, r + dr, T)`` buffer of latents and rotary keys,
+tokens minor) whose row axis, axis 1, is the slot; a request occupies
+one slot row from admission to completion.  Allocation and
 free are O(1) host-side bookkeeping — cache buffers are written in
 place (row scatter), never rebuilt per request.
 
@@ -195,8 +196,9 @@ class StackedSlotArenas:
     decode caches live in one pytree with the islands folded into the
     row axis: attention k/v ``(reps, P * S, KH, D, T)`` — layer outermost,
     tokens minor — where row ``p * S + s`` is slot ``s`` of path ``p``
-    (int8 scales ``(reps, P * S, KH, T)``, SSM state ``(reps, P * S,
-    ...)``).  One decode dispatch then advances *every* island per tick
+    (int8 scales ``(reps, P * S, KH, T)``, latents ``(reps, P * S,
+    r + dr, T)``, SSM state ``(reps, P * S, ...)``; leading dense layers
+    under ``lead``).  One decode dispatch then advances *every* island per tick
     (the stacked-island tick) instead of one jit call per island from a
     Python loop — per Pathways, dispatch overhead rather than FLOPs
     dominates the many-small-islands regime — and it reads each layer in

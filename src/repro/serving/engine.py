@@ -49,7 +49,7 @@ import numpy as np
 
 from repro.models import api
 from repro.models.config import ModelConfig
-from repro.models.lm import apply_lm
+from repro.models.lm import ROUTING, apply_lm
 
 from repro.obs import as_telemetry
 
@@ -81,13 +81,40 @@ def decode_program(cfg: ModelConfig, paths: Optional[int] = None):
     ``api.stack_paths``, tokens, positions and mask ``(P * S,)``
     path-major, against the row-folded arena — one dispatch advances
     every island, touching the KV arena only by ``flash_decode``'s read
-    and one masked token write per row and layer."""
+    and one masked token write per row and layer.  With MoE layers the
+    first output is (logits, routing): see :func:`_with_counts`."""
     def _decode_one(params, tok, cache, idx, mask):
         logits, cache = api.serve_step(params, cfg, {"tokens": tok}, cache,
-                                       idx, mask=mask, paths=paths)
-        return logits[:, 0], cache
+                                       idx, mask=mask, paths=paths,
+                                       counts=cfg.moe is not None)
+        return _with_counts(logits[:, 0], cache)
 
     return jax.jit(_decode_one, donate_argnums=2)
+
+
+def _with_counts(logits, cache):
+    """A program's outputs: (logits, cache), or ((logits, routing), cache)
+    where the step's cache holds its MoE routing (``lm.ROUTING``): the
+    routing counts and the experts each token ran travel beside the
+    logits and reach the host in the same fetch."""
+    if ROUTING not in cache:
+        return logits, cache
+    cache = dict(cache)
+    return (logits, cache.pop(ROUTING)), cache
+
+
+def _stack(paths):
+    """The engine's one copy of the stacked tick's weights: a single
+    path's own tree, else ``api.stack_paths``."""
+    return paths[0] if len(paths) == 1 else api.stack_paths(paths)
+
+
+# most prompt positions (rows x bucket) one bucketed prefill call
+# computes; a larger admission group is split, one row per call at the
+# least.  Bounds a prefill's activations and its rows' cache beside the
+# arena: Moonlight's 8192-position rows (85 MB of latents each) next to a
+# 2.7 GB arena; the 150M cell's largest call, 4 slots x 1024, is this.
+PREFILL_TOKENS = 4096
 
 
 def _default_buckets(cache_len: int):
@@ -203,6 +230,10 @@ class FinishedRequest:
     swapped_midstream: bool = False   # a live hot-swap hit this request
     priority: int = 1
     preemptions: int = 0        # times a high-priority admit evicted it
+    # MoE layers: the experts each position ran (MoE layers, positions,
+    # k), prompt and generated tokens but the last; None where a
+    # re-prefill or a prefix-cache hit left positions unrecorded
+    experts: Optional[np.ndarray] = None
 
     @property
     def latency(self) -> float:
@@ -244,18 +275,19 @@ class _EngineBase:
         self.cache_len = opts.cache_len
 
         cfg_ = cfg
-        # bind only the feature params, not the whole path list: the
-        # closure must not pin a superseded version's full parameter
-        # set in memory after a hot swap
-        feat_src = opts.feat_params if opts.feat_params is not None \
-            else path_params_list[0]
+        # bind only the feature params, not the whole path list, and only
+        # where a router reads them: the closure must not pin a
+        # superseded version's (or a stacked engine's) parameters
+        if opts.router is not None:
+            feat_src = opts.feat_params if opts.feat_params is not None \
+                else path_params_list[0]
 
-        @jax.jit
-        def _feats(tokens):
-            h, _ = apply_lm(feat_src, cfg_, tokens, return_hidden=True)
-            return jnp.mean(h.astype(jnp.float32), axis=1)
+            @jax.jit
+            def _feats(tokens):
+                h, _ = apply_lm(feat_src, cfg_, tokens, return_hidden=True)
+                return jnp.mean(h.astype(jnp.float32), axis=1)
 
-        self._feats = _feats
+            self._feats = _feats
 
     def route(self, tokens) -> np.ndarray:
         if self.route_fn is not None:
@@ -401,7 +433,7 @@ class ContinuousBatchingEngine(_EngineBase):
         super().__init__(cfg, path_params_list, options=options,
                          **legacy)
         opts = self.options               # resolved by the base
-        path_params_list = self.paths     # resolved by the base (registry)
+        path_params_list = self._paths    # resolved by the base (registry)
         cache_len = self.cache_len
         slots_per_path = opts.slots_per_path
         self.reroute_every = opts.reroute_every
@@ -419,7 +451,8 @@ class ContinuousBatchingEngine(_EngineBase):
         # pad tokens are causally invisible to attention rows, but a
         # recurrent SSM state (or enc-dec replay) would absorb them
         can_bucket = (not api.is_encdec(cfg)
-                      and all(spec.mixer == "attn" for spec in cfg.pattern))
+                      and all(spec.mixer in ("attn", "mla")
+                              for spec in cfg.pattern))
         self.bucketed = can_bucket if opts.bucketed_prefill is None \
             else opts.bucketed_prefill
         if self.bucketed and not can_bucket:
@@ -433,16 +466,26 @@ class ContinuousBatchingEngine(_EngineBase):
         # §2.4.3 migration re-prefills of the running text — hits the
         # warmed, bounded compile set instead of an exact-length compile
         self.prefill_buckets = tuple(sorted(set(buckets) | {cache_len}))
+        self.num_paths = num_paths
         if self.stacked:
-            self._stacked_params = api.stack_paths(path_params_list)
+            # the one copy of the weights the engine holds: every program
+            # reads path p out of it (one path: its own tree)
+            self._stack_of = None if num_paths == 1 else num_paths
+            self._stacked_params = _stack(path_params_list)
+            self._paths = None
             self._stacked_arenas = StackedSlotArenas(
                 cfg, num_paths, slots_per_path, cache_len)
             self.arenas = self._stacked_arenas.views
         else:
+            self._stack_of = None
             self._stacked_params = None
             self._stacked_arenas = None
             self.arenas = [SlotArena(cfg, slots_per_path, cache_len)
                            for _ in path_params_list]
+        self._counts = []          # routing counts fetched in this span
+        self._experts = None       # the last fetch's experts per row
+        # each path's index on the device, made once (not per dispatch)
+        self._path_ids = [jnp.int32(p) for p in range(num_paths)]
         self.scheduler = Scheduler(num_paths)
         self.in_flight: Dict[int, RequestState] = {}
         self.ticks = 0
@@ -458,71 +501,97 @@ class ContinuousBatchingEngine(_EngineBase):
         # device work completes, so TTFT includes that tick's compute
         self._new_first: list = []
         cfg_ = cfg
+        moe = cfg.moe is not None
 
+        # every program takes the weights as (params, p): the stacked
+        # tree and the path to read from it, or one path's own tree and
+        # p None (see ``_weights``)
         @jax.jit
-        def _prefill(params, tokens):
-            logits, cache = api.prefill(params, cfg_, {"tokens": tokens},
-                                        cache_len)
-            return logits[:, -1], cache
+        def _prefill(params, p, tokens):
+            last = jnp.full((tokens.shape[0],), tokens.shape[1] - 1,
+                            jnp.int32)
+            return _with_counts(*api.prefill(
+                params, cfg_, {"tokens": tokens}, cache_len, last=last,
+                path=p, counts=moe))
 
         self._prefill = _prefill
 
         @jax.jit
-        def _prefill_bucketed(params, tokens, last):
-            """Padded-bucket prefill: per-row gather of the logits at
-            each prompt's true last token (pad rows/tails ignored)."""
-            logits, cache = api.prefill(params, cfg_, {"tokens": tokens},
-                                        cache_len)
-            lg = jnp.take_along_axis(
-                logits, last[:, None, None], axis=1)[:, 0]
-            return lg, cache
+        def _prefill_bucketed(params, p, tokens, last):
+            """Padded-bucket prefill: each row unembeds only its true
+            last token's position (pad rows/tails ignored)."""
+            return _with_counts(*api.prefill(
+                params, cfg_, {"tokens": tokens}, cache_len, last=last,
+                path=p, counts=moe))
 
         self._prefill_bucketed = _prefill_bucketed
 
-        def _extend_one(params, tok, cache, idx):
+        def _extend_one(params, p, tok, cache, idx):
             logits, cache = api.serve_step(params, cfg_, {"tokens": tok},
-                                           cache, idx)
+                                           cache, idx, path=p)
             return logits[:, 0], cache
 
         # prefix-cache extension: replay an uncached prompt tail into a
         # stored single-slot row — fixed (1, 1) token shape, so the
         # whole extension machinery costs one jit entry
-        self._extend = jax.jit(_extend_one, donate_argnums=2)
+        self._extend = jax.jit(_extend_one, donate_argnums=3)
 
         self._decode_masked = decode_program(cfg)
         # stacked-island tick: one dispatch advances every island
-        self._decode_stacked = decode_program(cfg, num_paths)
+        self._decode_stacked = decode_program(cfg, self._stack_of)
 
-        def _decode_island(params, row0, tok, stacked_cache, idx, mask):
+        def _decode_island(params, p, row0, tok, stacked_cache, idx, mask):
             """Single-island decode against the stacked arena, in place:
             the island's rows start at ``row0``.  Used by the hybrid tick
             when few islands have work — a full stacked dispatch would
             burn (P-k)/P of its compute on empty islands."""
             logits, cache = api.serve_step(
                 params, cfg_, {"tokens": tok}, stacked_cache, idx,
-                mask=mask, row_offset=row0)
-            return logits[:, 0], cache
+                mask=mask, row_offset=row0, path=p, counts=moe)
+            return _with_counts(logits[:, 0], cache)
 
-        self._decode_island = jax.jit(_decode_island, donate_argnums=3)
+        self._decode_island = jax.jit(_decode_island, donate_argnums=4)
 
         def _restack(old, *new):
             return jax.tree_util.tree_map(lambda o, n: n.astype(o.dtype),
-                                          old, api.stack_paths(new))
+                                          old, _stack(new))
 
         # hot-swap double-buffering: the outgoing stacked tree is
         # donated, so XLA reuses its buffers for the incoming stack
         # instead of holding both full param sets alive
         self._restack = jax.jit(_restack, donate_argnums=0)
 
+    @property
+    def paths(self):
+        """Each path's weights (in stacked mode, slices of the one
+        stacked tree; for inspection and tests)."""
+        if not self.stacked:
+            return self._paths
+        return [api.path_params(self._stacked_params, p, self._stack_of)
+                for p in range(self.num_paths)]
+
+    @paths.setter
+    def paths(self, value):
+        self._paths = list(value)
+
+    def _weights(self, path: int):
+        """``(params, p)`` for a program that runs path ``path``."""
+        if not self.stacked:
+            return self._paths[path], None
+        if self._stack_of is None:
+            return self._stacked_params, None
+        return self._stacked_params, self._path_ids[path]
+
     # -- hot swap (deployment registry) --------------------------------
     def _install(self, version: int, paths) -> None:
         """Swap the serving parameters between ticks.  Never recompiles:
         the new version has identical shapes/dtypes (same partition), so
         every warmed prefill/decode jit entry stays valid."""
-        self.paths = list(paths)
         if self.stacked:
             self._stacked_params = self._restack(self._stacked_params,
-                                                 *self.paths)
+                                                 *paths)
+        else:
+            self.paths = paths
         self._version = version
         self.swaps += 1
         self.last_swap_tick = self.ticks
@@ -564,23 +633,67 @@ class ContinuousBatchingEngine(_EngineBase):
         n = len(tokens)
         length = self._bucket(n) if self.bucketed else n
         with self.tel.span("serve.prefill", rows=1, padded_rows=1, tokens=n,
-                           padded_tokens=length, bucket=length):
+                           padded_tokens=length, bucket=length) as sp:
             if self.bucketed:
                 tok = np.zeros((1, length), np.int32)
                 tok[0, :n] = tokens
                 logits, cache = self._prefill_bucketed(
-                    self.paths[path], jnp.asarray(tok),
+                    *self._weights(path), jnp.asarray(tok),
                     jnp.asarray([n - 1], np.int32))
             else:
                 logits, cache = self._prefill(
-                    self.paths[path],
+                    *self._weights(path),
                     jnp.asarray(np.asarray(tokens, np.int32)[None]))
-            return self._fetch(logits)[0], cache
+            logits = self._fetch(logits)[0]
+            self._record_routing(sp)
+            return logits, cache
 
-    def _fetch(self, logits) -> np.ndarray:
-        """Copy logits to the host: where a tick waits for the device."""
+    def _fetch(self, out) -> np.ndarray:
+        """Copy a program's logits to the host: where a tick waits for
+        the device.  With MoE layers ``out`` is (logits, routing) and both
+        come over in the one transfer; the counts are kept for
+        :meth:`_record_routing`, the experts for :meth:`_note_experts`."""
+        logits, routing = out if isinstance(out, tuple) else (out, None)
+        self._experts = None
         with self.tel.span("serve.fetch", bytes=logits.nbytes):
-            return np.asarray(logits)
+            if routing is None:
+                return np.asarray(logits)
+            logits, routing = jax.device_get((logits, routing))
+        self._counts.append(routing["counts"])
+        self._experts = routing["experts"]
+        return logits
+
+    def _prompt_experts(self, row: int, n: int):
+        """The record of the experts a prompt's ``n`` positions ran, row
+        ``row`` of the last fetched prefill; None without MoE layers."""
+        if self._experts is None:
+            return None
+        return [self._experts[:, row, :n]]
+
+    def _note_experts(self, states, rows) -> None:
+        """Add to each state's record the experts its newest position ran,
+        row ``rows[i]`` of the last fetched decode.  A decode that brought
+        none back ends the records: a finished request carries the
+        experts of every position it ran, or none."""
+        ex = self._experts
+        for st, r in zip(states, rows):
+            if st.experts is None:
+                continue
+            if ex is None:
+                st.experts = None
+            else:
+                st.experts.append(ex[:, r:r + 1])
+
+    def _record_routing(self, span) -> None:
+        """Put the routing counts fetched since the last call on
+        ``span``: ``experts_hit``, the experts chosen summed over the MoE
+        layers and programs, and ``max_expert_tokens``, the most tokens
+        one expert got in one layer.  Nothing without MoE layers."""
+        if self._counts:
+            c = np.concatenate(self._counts)
+            self._counts = []
+            span.set(experts_hit=int(c[:, 0].sum()),
+                     max_expert_tokens=int(c[:, 1].max()))
 
     def _reprefill_inflight(self) -> None:
         """Live-swap migration: rebuild every in-flight request's cache
@@ -593,6 +706,7 @@ class ContinuousBatchingEngine(_EngineBase):
             logits, cache = self._prefill_running(st.path, st.tokens)
             self.arenas[st.path].write_slots(cache, [st.slot],
                                              [len(st.tokens)])
+            st.experts = None          # earlier positions ran otherwise
             st.next_logits = logits
             st.prefilled_this_tick = True
             st.swapped_midstream = True
@@ -605,6 +719,20 @@ class ContinuousBatchingEngine(_EngineBase):
             return jax.tree_util.tree_leaves(self._stacked_arenas.cache)
         return [leaf for a in self.arenas
                 for leaf in jax.tree_util.tree_leaves(a.cache)]
+
+    def _row_buckets(self, length: int) -> list:
+        """The padded row counts of a bucket's prefill calls: powers of
+        two up to the slots, at most ``PREFILL_TOKENS`` positions (one
+        row at least)."""
+        cap = min(self.arenas[0].num_slots, max(1, PREFILL_TOKENS // length))
+        sizes, r = [], 1
+        while r < cap:
+            sizes.append(r)
+            r <<= 1
+        sizes.append(r)
+        if r > 1 and r * length > PREFILL_TOKENS:
+            sizes.pop()
+        return sizes
 
     def _bucket(self, n: int) -> int:
         """Smallest configured bucket >= n (always exists: the bucket
@@ -623,25 +751,22 @@ class ContinuousBatchingEngine(_EngineBase):
         prefill compiles per exact prompt length and cannot be warmed
         ahead of the trace.)"""
         slots = self.arenas[0].num_slots
-        sizes, r = [], 1
-        while r < slots:
-            sizes.append(r)
-            r <<= 1
-        sizes.append(r)
-        seen = set()
-        warm_paths = []
-        for p in self.paths:
-            sig = tuple((leaf.shape, str(leaf.dtype))
-                        for leaf in jax.tree_util.tree_leaves(p))
-            if sig not in seen:
-                seen.add(sig)
-                warm_paths.append(p)
+        if self.stacked:
+            warm_paths = [self._weights(0)]    # one tree, one signature
+        else:
+            seen, warm_paths = set(), []
+            for p, params in enumerate(self._paths):
+                sig = tuple((leaf.shape, str(leaf.dtype))
+                            for leaf in jax.tree_util.tree_leaves(params))
+                if sig not in seen:
+                    seen.add(sig)
+                    warm_paths.append(self._weights(p))
         if self.bucketed:
-            for params in warm_paths:
+            for weights in warm_paths:
                 for length in self.prefill_buckets:
-                    for rows in sizes:
+                    for rows in self._row_buckets(length):
                         self._prefill_bucketed(
-                            params, jnp.zeros((rows, length), jnp.int32),
+                            *weights, jnp.zeros((rows, length), jnp.int32),
                             jnp.full((rows,), length - 1, jnp.int32))
         if self.stacked:
             sa = self._stacked_arenas
@@ -652,13 +777,18 @@ class ContinuousBatchingEngine(_EngineBase):
                 self._stacked_params, tok, sa.cache,
                 jnp.asarray(sa.positions.reshape(-1)), mask)  # no-op
             _, sa.cache = self._decode_island(
-                self.paths[0], jnp.int32(0), tok[:sa.num_slots], sa.cache,
-                jnp.asarray(sa.positions[0]), mask[:sa.num_slots])
-            # warm the hot-swap install too: the swap contract is "no
-            # compile inside a serving tick", which must include the
-            # first swap's restack dispatch
-            self._stacked_params = self._restack(self._stacked_params,
-                                                 *self.paths)
+                *self._weights(0), jnp.int32(0), tok[:sa.num_slots],
+                sa.cache, jnp.asarray(sa.positions[0]),
+                mask[:sa.num_slots])
+            # compile the hot-swap install too, without running it (a
+            # run would need a second copy of the weights): the swap
+            # contract is "no compile inside a serving tick", which must
+            # include the first swap's restack dispatch
+            shapes = jax.eval_shape(lambda t: t, self._stacked_params)
+            one = jax.eval_shape(
+                lambda t: api.path_params(t, 0, self._stack_of), shapes)
+            self._restack = self._restack.lower(
+                shapes, *[one] * self.num_paths).compile()
         else:
             for p, params in enumerate(self.paths):
                 arena = self.arenas[p]
@@ -776,7 +906,7 @@ class ContinuousBatchingEngine(_EngineBase):
                                bucket=s0 - n):
                 for t in range(n, s0):
                     lg, row = self._extend(
-                        self.paths[path],
+                        *self._weights(path),
                         jnp.asarray([[r.prompt[t]]], jnp.int32),
                         row, jnp.int32(t))
                 logits = self._fetch(lg)[0]
@@ -820,6 +950,7 @@ class ContinuousBatchingEngine(_EngineBase):
                 logits, cache = self._prefill_running(path, st.tokens)
                 arena.write_slots(cache, [slot], [len(st.tokens)])
                 st.path, st.slot = path, slot
+                st.experts = None      # earlier positions ran otherwise
                 st.next_logits = logits
                 st.prefilled_this_tick = True
                 self.in_flight[r.rid] = st
@@ -832,18 +963,21 @@ class ContinuousBatchingEngine(_EngineBase):
             for r in reqs:
                 s0 = len(r.prompt)
                 with self.tel.span("serve.prefill", rows=1, padded_rows=1,
-                                   tokens=s0, padded_tokens=s0, bucket=s0):
+                                   tokens=s0, padded_tokens=s0,
+                                   bucket=s0) as sp:
                     logits, cache = self._prefill(
-                        self.paths[path], jnp.asarray(r.prompt[None]))
+                        *self._weights(path), jnp.asarray(r.prompt[None]))
                     slot = arena.alloc()
                     arena.write_slots(cache, [slot], [s0])
                     logits = self._fetch(logits)[0]
+                    self._record_routing(sp)
                 self.in_flight[r.rid] = RequestState(
                     req=r, path=path, slot=slot,
                     tokens=list(map(int, r.prompt)),
                     next_logits=logits,
                     prefilled_this_tick=True, admitted_at=now,
-                    version=self._version)
+                    version=self._version,
+                    experts=self._prompt_experts(0, s0))
                 if self.prefix_cache is not None:
                     self.prefix_cache.put(path, self._version, r.prompt,
                                           cache, logits)
@@ -851,7 +985,12 @@ class ContinuousBatchingEngine(_EngineBase):
         groups: Dict[int, List[Request]] = {}
         for r in reqs:
             groups.setdefault(self._bucket(len(r.prompt)), []).append(r)
+        calls = []
         for length, group in sorted(groups.items()):
+            most = self._row_buckets(length)[-1]
+            calls += [(length, group[i:i + most])
+                      for i in range(0, len(group), most)]
+        for length, group in calls:
             rows = 1 << (len(group) - 1).bit_length()   # batch bucket
             tok = np.zeros((rows, length), np.int32)
             last = np.zeros(rows, np.int32)
@@ -861,20 +1000,24 @@ class ContinuousBatchingEngine(_EngineBase):
             with self.tel.span("serve.prefill", rows=len(group),
                                padded_rows=rows,
                                tokens=sum(len(r.prompt) for r in group),
-                               padded_tokens=rows * length, bucket=length):
+                               padded_tokens=rows * length,
+                               bucket=length) as sp:
                 logits, cache = self._prefill_bucketed(
-                    self.paths[path], jnp.asarray(tok), jnp.asarray(last))
+                    *self._weights(path), jnp.asarray(tok),
+                    jnp.asarray(last))
                 slots = [arena.alloc() for _ in group]
                 arena.write_slots(cache, slots,
                                   [len(r.prompt) for r in group])
                 logits = self._fetch(logits)
+                self._record_routing(sp)
             for i, r in enumerate(group):
                 self.in_flight[r.rid] = RequestState(
                     req=r, path=path, slot=slots[i],
                     tokens=list(map(int, r.prompt)),
                     next_logits=logits[i],
                     prefilled_this_tick=True, admitted_at=now,
-                    version=self._version)
+                    version=self._version,
+                    experts=self._prompt_experts(i, len(r.prompt)))
                 if self.prefix_cache is not None:
                     self.prefix_cache.put(
                         path, self._version, r.prompt,
@@ -900,11 +1043,12 @@ class ContinuousBatchingEngine(_EngineBase):
         islands = len(self.arenas) if dense else len(active)
         with self.tel.span("serve.decode", rows=len(rows),
                            slots=islands * self.arenas[0].num_slots,
-                           dense=dense):
+                           dense=dense) as sp:
             if self.stacked:
                 self._decode_tick_stacked(rows, active, dense)
             else:
                 self._decode_tick_islands(rows, active)
+            self._record_routing(sp)
 
     def _decode_tick_islands(self, rows, active) -> None:
         for p in active:
@@ -917,9 +1061,10 @@ class ContinuousBatchingEngine(_EngineBase):
                 tok[st.slot, 0] = st.tokens[-1]
                 mask[st.slot] = True
             logits, arena.cache = self._decode_masked(
-                self.paths[p], jnp.asarray(tok), arena.cache,
+                self._paths[p], jnp.asarray(tok), arena.cache,
                 jnp.asarray(arena.decode_indices()), jnp.asarray(mask))
             logits = self._fetch(logits)
+            self._note_experts(mine, [st.slot for st in mine])
             for st in mine:
                 st.next_logits = logits[st.slot]
 
@@ -940,6 +1085,8 @@ class ContinuousBatchingEngine(_EngineBase):
                 jnp.asarray(mask.reshape(-1)))
             logits = self._fetch(logits).reshape(
                 sa.num_paths, sa.num_slots, -1)
+            self._note_experts(rows, [st.path * sa.num_slots + st.slot
+                                      for st in rows])
             for st in rows:
                 st.next_logits = logits[st.path, st.slot]
             return
@@ -948,10 +1095,12 @@ class ContinuousBatchingEngine(_EngineBase):
         out = {}
         for p in active:
             lg, sa.cache = self._decode_island(
-                self.paths[p], jnp.int32(p * sa.num_slots),
+                *self._weights(p), jnp.int32(p * sa.num_slots),
                 jnp.asarray(tok[p]), sa.cache,
                 jnp.asarray(sa.positions[p]), jnp.asarray(mask[p]))
             out[p] = self._fetch(lg)
+            mine = [st for st in rows if st.path == p]
+            self._note_experts(mine, [st.slot for st in mine])
         for st in rows:
             st.next_logits = out[st.path][st.slot]
 
@@ -975,7 +1124,9 @@ class ContinuousBatchingEngine(_EngineBase):
                     version=st.version,
                     swapped_midstream=st.swapped_midstream,
                     priority=st.req.priority,
-                    preemptions=st.preemptions)
+                    preemptions=st.preemptions,
+                    experts=(np.concatenate(st.experts, axis=1)
+                             if st.experts else None))
                 done.append(fin)
                 del self.in_flight[st.req.rid]
                 self.scheduler.record_completion()
@@ -1006,6 +1157,7 @@ class ContinuousBatchingEngine(_EngineBase):
         self.arenas[new_p].write_slots(cache, [slot], [len(st.tokens)])
         self.arenas[st.path].free(st.slot)
         st.path, st.slot = new_p, slot
+        st.experts = None              # earlier positions ran otherwise
         st.next_logits = logits
         st.switches += 1
         st.prefilled_this_tick = True

@@ -64,6 +64,9 @@ class RequestState:
     swapped_midstream: bool = False   # a live hot-swap hit this request
     first_token_at: Optional[float] = None
     preemptions: int = 0          # times this request lost its slot
+    # MoE layers: the experts each position ran, (layers, n, k) pieces
+    # in position order; None once a position goes unrecorded
+    experts: Optional[list] = None
 
     @property
     def emitted(self) -> int:

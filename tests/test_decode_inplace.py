@@ -204,7 +204,8 @@ def test_island_tick_matches_select_oracle(paths):
         p = t % PATHS
         mine = slice(p * SLOTS, (p + 1) * SLOTS)
         logits, new = eng._decode_island(
-            eng.paths[p], jnp.int32(p * SLOTS), jnp.asarray(tok[mine]),
+            eng._stacked_params, jnp.int32(p), jnp.int32(p * SLOTS),
+            jnp.asarray(tok[mine]),
             _copy(cache), jnp.asarray(pos[mine]), jnp.asarray(mask[mine]))
         island = np.zeros(rows, bool)
         island[mine] = mask[mine]

@@ -28,11 +28,12 @@ def test_dense_vs_scatter_dispatch_equal_at_high_capacity():
     key = jax.random.PRNGKey(0)
     cfg, p = _moe_setup(key, cap=8.0)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model))
-    y1, a1 = moe_dense_dispatch(p, cfg, x, group_size=64)
-    y2, a2 = moe_scatter_dispatch(p, cfg, x)
+    y1, a1, e1 = moe_dense_dispatch(p, cfg, x, group_size=64)
+    y2, a2, e2 = moe_scatter_dispatch(p, cfg, x)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(float(a1), float(a2), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(e1), np.asarray(e2))
 
 
 @settings(max_examples=8, deadline=None)
@@ -44,7 +45,7 @@ def test_moe_gate_weights_partition_of_unity(seed, e, k):
     key = jax.random.PRNGKey(seed)
     cfg, p = _moe_setup(key, num_experts=e, top_k=k, cap=8.0)
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, 16, cfg.d_model))
-    y, aux = moe_scatter_dispatch(p, cfg, x)
+    y, aux, _ = moe_scatter_dispatch(p, cfg, x)
     assert np.isfinite(np.asarray(y)).all()
     assert float(aux) >= 0.0
 
@@ -56,7 +57,7 @@ def test_moe_aux_loss_balanced_router_is_minimal():
     cfg, p = _moe_setup(key)
     # random inputs -> near-uniform; aux should be within 2x of minimum
     x = jax.random.normal(jax.random.PRNGKey(4), (4, 64, cfg.d_model))
-    _, aux = moe_dense_dispatch(p, cfg, x, group_size=64)
+    _, aux, _ = moe_dense_dispatch(p, cfg, x, group_size=64)
     assert float(aux) < cfg.moe.router_aux_weight * 3.0
 
 

@@ -23,12 +23,14 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.decode_attention import flash_decode
+from repro.kernels.mla_decode import mla_decode
 from repro.kernels.ops import flash_attention_trainable
 from repro.models import api
 from repro.serving.engine import decode_program
 
 HEADS, HEAD_DIM, SEQ = 16, 64, 2048      # dipaco-150m: 16 x 64, T <= 2048
 V5E_HBM = 16 * 2**30
+V5E_OFFERED = 15.75e9                    # what the chip gives a program
 
 
 @pytest.fixture(scope="module")
@@ -190,3 +192,84 @@ def test_engine_stacked_tick_is_in_place(one_chip):
     layer_kv = rows * cfg.num_kv_heads * cfg.head_dim * cache_len * 2
     size, name = _largest_result(hlo, ("select", "copy", "dynamic-slice"))
     assert size < layer_kv, (name, size, layer_kv)
+
+
+# Moonlight-16B-A3B as the benchmark serves it: 9 layers, 32 rows of an
+# 8192-position latent arena, the Pallas kernels
+MOONLIGHT_ROWS, MOONLIGHT_CACHE = 32, 8192
+
+
+def _moonlight(one_chip):
+    cfg = get_config("moonlight-16b-a3b").replace(num_layers=9,
+                                                  attn_impl="pallas")
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(
+        lambda: api.init_model(jax.random.PRNGKey(0), cfg)[0]))
+    cache = place(jax.eval_shape(lambda: api.init_serve_cache(
+        cfg, MOONLIGHT_ROWS, MOONLIGHT_CACHE)))
+    return cfg, params, cache
+
+
+def _total(mem):
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def test_mla_decode_compiles(one_chip):
+    """32 rows at a traced offset into a 3-layer, 64-row latent arena of
+    576 x 8192, read at a traced layer."""
+    q = jax.ShapeDtypeStruct((32, 16, 576), jnp.float32, sharding=one_chip)
+    cache = jax.ShapeDtypeStruct((3, 64, 576, MOONLIGHT_CACHE), jnp.bfloat16,
+                                 sharding=one_chip)
+    ci = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    at = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = _compile(lambda q, c, ci, layer, row0: mla_decode(
+        q, c, ci, layer, kv_rank=512, scale=192 ** -0.5, row_offset=row0),
+        q, cache, ci, at, at).as_text()
+    assert "mla_decode" in text
+
+
+def test_moonlight_decode_tick_fits_v5e(one_chip):
+    """The engine's decode tick (one path: ``decode_program(cfg)``) at 32
+    rows x 8192: 10.86 GB of weights and the 2.72 GB latent arena as
+    arguments, the arena updated in place (aliased), and temporaries
+    that fit beside them; the latent attention and the expert matmuls
+    are the Pallas kernels."""
+    cfg, params, cache = _moonlight(one_chip)
+    vec = jax.ShapeDtypeStruct((MOONLIGHT_ROWS,), jnp.int32,
+                               sharding=one_chip)
+    compiled = decode_program(cfg).lower(
+        params, jax.ShapeDtypeStruct((MOONLIGHT_ROWS, 1), jnp.int32,
+                                     sharding=one_chip),
+        cache, vec, jax.ShapeDtypeStruct((MOONLIGHT_ROWS,), bool,
+                                         sharding=one_chip)).compile()
+    hlo = compiled.as_text()
+    assert re.search(r"%mla_decode[.\d]* = ", hlo)
+    assert re.search(r"%gmm[.\d]* = ", hlo)
+    mem = compiled.memory_analysis()
+    arena = sum(a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes >= arena, mem
+    assert _total(mem) < V5E_OFFERED, mem
+
+
+def test_moonlight_largest_prefill_fits_v5e(one_chip):
+    """The largest prefill bucket, one row of 8192 tokens (the engine's
+    bucketed prefill: head at the last position, routing counts), fits
+    beside the latent arena that stays resident."""
+    cfg, params, cache = _moonlight(one_chip)
+    compiled = jax.jit(lambda p, t, last: api.prefill(
+        p, cfg, {"tokens": t}, MOONLIGHT_CACHE, last=last, counts=True)
+    ).lower(params, jax.ShapeDtypeStruct((1, MOONLIGHT_CACHE), jnp.int32,
+                                         sharding=one_chip),
+            jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    ).compile()
+    assert re.search(r"%gmm[.\d]* = ", compiled.as_text())
+    arena = sum(a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(cache))
+    assert _total(compiled.memory_analysis()) + arena < V5E_OFFERED
