@@ -198,9 +198,9 @@ def decode_tick_seconds(engine, n: int = 10) -> float:
     times = []
     for _ in range(n):
         t0 = time.perf_counter()
-        logits, sa.cache = engine._decode_stacked(
+        ids, sa.cache = engine._decode_stacked(
             engine._stacked_params, tok, sa.cache, pos, mask)
-        jax.block_until_ready(logits)
+        jax.block_until_ready(ids)
         times.append(time.perf_counter() - t0)
     return float(np.median(times))
 

@@ -55,10 +55,11 @@ benchmark metric (``bench/metrics/``).
                           ``padded_rows``, ``tokens``: real prompt
                           tokens, ``padded_tokens``: the tokens the
                           program computes, ``bucket``)
-``serve.fetch``           a device-to-host copy of logits (``bytes``)
+``serve.fetch``           a device-to-host copy of a program's next-token
+                          ids and MoE routing (``bytes``)
 ``serve.decode``          the tick's decode dispatches (``rows``,
                           ``slots``, ``dense``)
-``serve.emit``            greedy tokens, retirement, migration
+``serve.emit``            fetched tokens appended, retirement, migration
                           (``rows``, ``finished``)
 ========================  =============================================
 

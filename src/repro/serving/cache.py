@@ -20,7 +20,7 @@ prefill KV rows are remembered content-keyed by
 ``(path, version, prompt tokens)`` so a repeated prompt — or one whose
 prefix another request already prefills — skips (part of) its prefill
 forward.  Reuse is exact-by-construction for full-prompt hits (the
-stored row and next-token logits came from an identical forward) and
+stored row and greedy next token came from an identical forward) and
 greedy-token-identical for prefix extensions (single-token replay is
 the same §2.4.3 re-prefill primitive the token-identity matrix pins
 against one-forward prefill).
@@ -124,7 +124,7 @@ class PrefixCache:
 
     Entries map ``(path, version, tokens)`` to a single-slot cache
     pytree (leaves ``(reps, 1, ...)`` — one arena row) plus the
-    next-token logits that forward produced.  ``lookup`` returns the
+    greedy next token that forward produced.  ``lookup`` returns the
     longest usable entry: the exact prompt when present, else the
     longest *strict* prefix (the engine replays the remaining tokens
     through single-row decode steps — a fixed (1, 1) shape, so the
@@ -153,17 +153,17 @@ class PrefixCache:
         return (int(path), int(version), tuple(int(t) for t in tokens))
 
     def put(self, path: int, version: int, tokens, row_cache,
-            logits) -> None:
+            next_token: int) -> None:
         key = self._key(path, version, tokens)
         self._entries.pop(key, None)
-        self._entries[key] = (row_cache, np.asarray(logits))
+        self._entries[key] = (row_cache, int(next_token))
         while len(self._entries) > self.max_entries:
             self._entries.popitem(last=False)
 
     def lookup(self, path: int, version: int,
-               tokens) -> Optional[Tuple[int, object, np.ndarray]]:
+               tokens) -> Optional[Tuple[int, object, int]]:
         """Longest usable entry for ``tokens``: ``(n_cached, row_cache,
-        logits)`` with ``n_cached == len(tokens)`` for an exact hit, a
+        next_token)`` with ``n_cached == len(tokens)`` for an exact hit, a
         shorter strict prefix otherwise; None on miss.  Prefix probing
         walks backwards from the full prompt so the first find is the
         longest (prompts are short relative to cache_len; the probe is

@@ -81,26 +81,34 @@ def decode_program(cfg: ModelConfig, paths: Optional[int] = None):
     ``api.stack_paths``, tokens, positions and mask ``(P * S,)``
     path-major, against the row-folded arena — one dispatch advances
     every island, touching the KV arena only by ``flash_decode``'s read
-    and one masked token write per row and layer.  With MoE layers the
-    first output is (logits, routing): see :func:`_with_counts`."""
+    and one masked token write per row and layer.  The first output is
+    each row's greedy next token: see :func:`_greedy`."""
     def _decode_one(params, tok, cache, idx, mask):
         logits, cache = api.serve_step(params, cfg, {"tokens": tok}, cache,
                                        idx, mask=mask, paths=paths,
                                        counts=cfg.moe is not None)
-        return _with_counts(logits[:, 0], cache)
+        return _greedy(logits[:, 0], cache)
 
     return jax.jit(_decode_one, donate_argnums=2)
 
 
-def _with_counts(logits, cache):
-    """A program's outputs: (logits, cache), or ((logits, routing), cache)
-    where the step's cache holds its MoE routing (``lm.ROUTING``): the
-    routing counts and the experts each token ran travel beside the
-    logits and reach the host in the same fetch."""
+def _greedy(logits, cache):
+    """A program's outputs from its model step's ``(rows, vocab)``
+    logits: (ids, cache), where ids ``(rows,)`` int32 is each row's
+    greedy next token (the first index of the maximum, as
+    ``np.argmax``), so only ids cross to the host.  Where the step's
+    cache holds its MoE routing (``lm.ROUTING``) the first output is
+    (ids, routing): the routing counts and the experts each token ran
+    travel beside the ids and reach the host in the same fetch."""
+    # the pick reads the logits as the step writes them out: fused into
+    # the head's matmul, the reduce would see its unrounded sums and
+    # break bf16 ties otherwise than an argmax of the fetched logits
+    logits = jax.lax.optimization_barrier(logits)
+    ids = jnp.argmax(logits, -1).astype(jnp.int32)
     if ROUTING not in cache:
-        return logits, cache
+        return ids, cache
     cache = dict(cache)
-    return (logits, cache.pop(ROUTING)), cache
+    return (ids, cache.pop(ROUTING)), cache
 
 
 def _stack(paths):
@@ -510,7 +518,7 @@ class ContinuousBatchingEngine(_EngineBase):
         def _prefill(params, p, tokens):
             last = jnp.full((tokens.shape[0],), tokens.shape[1] - 1,
                             jnp.int32)
-            return _with_counts(*api.prefill(
+            return _greedy(*api.prefill(
                 params, cfg_, {"tokens": tokens}, cache_len, last=last,
                 path=p, counts=moe))
 
@@ -520,7 +528,7 @@ class ContinuousBatchingEngine(_EngineBase):
         def _prefill_bucketed(params, p, tokens, last):
             """Padded-bucket prefill: each row unembeds only its true
             last token's position (pad rows/tails ignored)."""
-            return _with_counts(*api.prefill(
+            return _greedy(*api.prefill(
                 params, cfg_, {"tokens": tokens}, cache_len, last=last,
                 path=p, counts=moe))
 
@@ -529,7 +537,7 @@ class ContinuousBatchingEngine(_EngineBase):
         def _extend_one(params, p, tok, cache, idx):
             logits, cache = api.serve_step(params, cfg_, {"tokens": tok},
                                            cache, idx, path=p)
-            return logits[:, 0], cache
+            return _greedy(logits[:, 0], cache)
 
         # prefix-cache extension: replay an uncached prompt tail into a
         # stored single-slot row — fixed (1, 1) token shape, so the
@@ -548,7 +556,7 @@ class ContinuousBatchingEngine(_EngineBase):
             logits, cache = api.serve_step(
                 params, cfg_, {"tokens": tok}, stacked_cache, idx,
                 mask=mask, row_offset=row0, path=p, counts=moe)
-            return _with_counts(logits[:, 0], cache)
+            return _greedy(logits[:, 0], cache)
 
         self._decode_island = jax.jit(_decode_island, donate_argnums=4)
 
@@ -629,7 +637,7 @@ class ContinuousBatchingEngine(_EngineBase):
     def _prefill_running(self, path: int, tokens):
         """Re-prefill a request's full running text on island ``path``
         (the §2.4.3 migration primitive shared by re-route moves and
-        live hot-swaps): returns (next-token logits row, cache)."""
+        live hot-swaps): returns (greedy next token, cache)."""
         n = len(tokens)
         length = self._bucket(n) if self.bucketed else n
         with self.tel.span("serve.prefill", rows=1, padded_rows=1, tokens=n,
@@ -637,31 +645,33 @@ class ContinuousBatchingEngine(_EngineBase):
             if self.bucketed:
                 tok = np.zeros((1, length), np.int32)
                 tok[0, :n] = tokens
-                logits, cache = self._prefill_bucketed(
+                ids, cache = self._prefill_bucketed(
                     *self._weights(path), jnp.asarray(tok),
                     jnp.asarray([n - 1], np.int32))
             else:
-                logits, cache = self._prefill(
+                ids, cache = self._prefill(
                     *self._weights(path),
                     jnp.asarray(np.asarray(tokens, np.int32)[None]))
-            logits = self._fetch(logits)[0]
+            token = int(self._fetch(ids)[0])
             self._record_routing(sp)
-            return logits, cache
+            return token, cache
 
     def _fetch(self, out) -> np.ndarray:
-        """Copy a program's logits to the host: where a tick waits for
-        the device.  With MoE layers ``out`` is (logits, routing) and both
-        come over in the one transfer; the counts are kept for
-        :meth:`_record_routing`, the experts for :meth:`_note_experts`."""
-        logits, routing = out if isinstance(out, tuple) else (out, None)
+        """Copy a program's next-token ids to the host: where a tick
+        waits for the device.  With MoE layers ``out`` is (ids, routing)
+        and both come over in the one transfer (``bytes`` counts them
+        all); the counts are kept for :meth:`_record_routing`, the
+        experts for :meth:`_note_experts`."""
+        ids, routing = out if isinstance(out, tuple) else (out, None)
         self._experts = None
-        with self.tel.span("serve.fetch", bytes=logits.nbytes):
+        nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(out))
+        with self.tel.span("serve.fetch", bytes=nbytes):
             if routing is None:
-                return np.asarray(logits)
-            logits, routing = jax.device_get((logits, routing))
+                return np.asarray(ids)
+            ids, routing = jax.device_get((ids, routing))
         self._counts.append(routing["counts"])
         self._experts = routing["experts"]
-        return logits
+        return ids
 
     def _prompt_experts(self, row: int, n: int):
         """The record of the experts a prompt's ``n`` positions ran, row
@@ -703,11 +713,11 @@ class ContinuousBatchingEngine(_EngineBase):
         stream and a fresh new-version generation — accepted, and the
         request is flagged."""
         for st in self.in_flight.values():
-            logits, cache = self._prefill_running(st.path, st.tokens)
+            token, cache = self._prefill_running(st.path, st.tokens)
             self.arenas[st.path].write_slots(cache, [st.slot],
                                              [len(st.tokens)])
             st.experts = None          # earlier positions ran otherwise
-            st.next_logits = logits
+            st.next_token = token
             st.prefilled_this_tick = True
             st.swapped_midstream = True
             st.version = self._version
@@ -873,7 +883,7 @@ class ContinuousBatchingEngine(_EngineBase):
                 arena.free(st.slot)
                 del self.in_flight[st.req.rid]
                 st.preemptions += 1
-                st.next_logits = None
+                st.next_token = None
                 st.prefilled_this_tick = False
                 self._preempted[st.req.rid] = st
                 self.scheduler.requeue(st.req, p)
@@ -884,8 +894,8 @@ class ContinuousBatchingEngine(_EngineBase):
         """Admit ``r`` from the cross-request prefix cache when (a
         prefix of) its prompt is cached under the current version.
 
-        Exact hits write the stored row + logits — bit-exact, both came
-        from an identical prefill forward.  Prefix hits replay only the
+        Exact hits write the stored row + next token — bit-exact, both
+        came from an identical prefill forward.  Prefix hits replay only the
         uncached tail through single-row decode steps (the same replay
         primitive the token-identity matrix pins against one-forward
         prefill) and promote the extended row to a full-prompt entry.
@@ -895,7 +905,7 @@ class ContinuousBatchingEngine(_EngineBase):
         hit = self.prefix_cache.lookup(path, self._version, r.prompt)
         if hit is None:
             return False
-        n, row, logits = hit
+        n, row, token = hit
         s0 = len(r.prompt)
         if n < s0:
             # copy the stored row: the replay loop donates its cache
@@ -905,19 +915,19 @@ class ContinuousBatchingEngine(_EngineBase):
                                tokens=s0 - n, padded_tokens=s0 - n,
                                bucket=s0 - n):
                 for t in range(n, s0):
-                    lg, row = self._extend(
+                    ids, row = self._extend(
                         *self._weights(path),
                         jnp.asarray([[r.prompt[t]]], jnp.int32),
                         row, jnp.int32(t))
-                logits = self._fetch(lg)[0]
+                token = int(self._fetch(ids)[0])
             self.prefix_cache.put(path, self._version, r.prompt, row,
-                                  logits)
+                                  token)
         slot = arena.alloc()
         arena.write_slots(row, [slot], [s0])
         self.in_flight[r.rid] = RequestState(
             req=r, path=path, slot=slot,
             tokens=list(map(int, r.prompt)),
-            next_logits=np.asarray(logits).copy(),
+            next_token=token,
             prefilled_this_tick=True, admitted_at=now,
             version=self._version)
         return True
@@ -947,11 +957,11 @@ class ContinuousBatchingEngine(_EngineBase):
                 # the §2.4.3 re-prefill primitive — greedy-identical
                 # to the uninterrupted continuation
                 slot = arena.alloc()
-                logits, cache = self._prefill_running(path, st.tokens)
+                token, cache = self._prefill_running(path, st.tokens)
                 arena.write_slots(cache, [slot], [len(st.tokens)])
                 st.path, st.slot = path, slot
                 st.experts = None      # earlier positions ran otherwise
-                st.next_logits = logits
+                st.next_token = token
                 st.prefilled_this_tick = True
                 self.in_flight[r.rid] = st
             elif not self._prefix_admit(path, r, arena, now):
@@ -965,22 +975,22 @@ class ContinuousBatchingEngine(_EngineBase):
                 with self.tel.span("serve.prefill", rows=1, padded_rows=1,
                                    tokens=s0, padded_tokens=s0,
                                    bucket=s0) as sp:
-                    logits, cache = self._prefill(
+                    ids, cache = self._prefill(
                         *self._weights(path), jnp.asarray(r.prompt[None]))
                     slot = arena.alloc()
                     arena.write_slots(cache, [slot], [s0])
-                    logits = self._fetch(logits)[0]
+                    token = int(self._fetch(ids)[0])
                     self._record_routing(sp)
                 self.in_flight[r.rid] = RequestState(
                     req=r, path=path, slot=slot,
                     tokens=list(map(int, r.prompt)),
-                    next_logits=logits,
+                    next_token=token,
                     prefilled_this_tick=True, admitted_at=now,
                     version=self._version,
                     experts=self._prompt_experts(0, s0))
                 if self.prefix_cache is not None:
                     self.prefix_cache.put(path, self._version, r.prompt,
-                                          cache, logits)
+                                          cache, token)
             return
         groups: Dict[int, List[Request]] = {}
         for r in reqs:
@@ -1002,19 +1012,19 @@ class ContinuousBatchingEngine(_EngineBase):
                                tokens=sum(len(r.prompt) for r in group),
                                padded_tokens=rows * length,
                                bucket=length) as sp:
-                logits, cache = self._prefill_bucketed(
+                ids, cache = self._prefill_bucketed(
                     *self._weights(path), jnp.asarray(tok),
                     jnp.asarray(last))
                 slots = [arena.alloc() for _ in group]
                 arena.write_slots(cache, slots,
                                   [len(r.prompt) for r in group])
-                logits = self._fetch(logits)
+                ids = self._fetch(ids).tolist()
                 self._record_routing(sp)
             for i, r in enumerate(group):
                 self.in_flight[r.rid] = RequestState(
                     req=r, path=path, slot=slots[i],
                     tokens=list(map(int, r.prompt)),
-                    next_logits=logits[i],
+                    next_token=ids[i],
                     prefilled_this_tick=True, admitted_at=now,
                     version=self._version,
                     experts=self._prompt_experts(i, len(r.prompt)))
@@ -1023,7 +1033,7 @@ class ContinuousBatchingEngine(_EngineBase):
                         path, self._version, r.prompt,
                         jax.tree_util.tree_map(
                             lambda x, i=i: x[:, i:i + 1], cache),
-                        logits[i])
+                        ids[i])
 
     def _decode_tick(self) -> None:
         """Advance every in-flight request one token.
@@ -1060,13 +1070,13 @@ class ContinuousBatchingEngine(_EngineBase):
                 arena.positions[st.slot] = len(st.tokens) - 1
                 tok[st.slot, 0] = st.tokens[-1]
                 mask[st.slot] = True
-            logits, arena.cache = self._decode_masked(
+            ids, arena.cache = self._decode_masked(
                 self._paths[p], jnp.asarray(tok), arena.cache,
                 jnp.asarray(arena.decode_indices()), jnp.asarray(mask))
-            logits = self._fetch(logits)
+            ids = self._fetch(ids).tolist()
             self._note_experts(mine, [st.slot for st in mine])
             for st in mine:
-                st.next_logits = logits[st.slot]
+                st.next_token = ids[st.slot]
 
     def _decode_tick_stacked(self, rows, active, dense) -> None:
         sa = self._stacked_arenas
@@ -1079,38 +1089,38 @@ class ContinuousBatchingEngine(_EngineBase):
         if dense:
             # dense tick: one dispatch advances every island; the arena's
             # rows are path-major, so (P, S) arrays fold to P * S rows
-            logits, sa.cache = self._decode_stacked(
+            ids, sa.cache = self._decode_stacked(
                 self._stacked_params, jnp.asarray(tok.reshape(-1, 1)),
                 sa.cache, jnp.asarray(sa.positions.reshape(-1)),
                 jnp.asarray(mask.reshape(-1)))
-            logits = self._fetch(logits).reshape(
-                sa.num_paths, sa.num_slots, -1)
+            ids = self._fetch(ids).tolist()
             self._note_experts(rows, [st.path * sa.num_slots + st.slot
                                       for st in rows])
             for st in rows:
-                st.next_logits = logits[st.path, st.slot]
+                st.next_token = ids[st.path * sa.num_slots + st.slot]
             return
         # sparse tick (e.g. trace drain): decode only the active
         # islands, each on its own rows of the stacked arena in place
         out = {}
         for p in active:
-            lg, sa.cache = self._decode_island(
+            ids, sa.cache = self._decode_island(
                 *self._weights(p), jnp.int32(p * sa.num_slots),
                 jnp.asarray(tok[p]), sa.cache,
                 jnp.asarray(sa.positions[p]), jnp.asarray(mask[p]))
-            out[p] = self._fetch(lg)
+            out[p] = self._fetch(ids).tolist()
             mine = [st for st in rows if st.path == p]
             self._note_experts(mine, [st.slot for st in mine])
         for st in rows:
-            st.next_logits = out[st.path][st.slot]
+            st.next_token = out[st.path][st.slot]
 
     def _emit_tick(self, now: float) -> List[FinishedRequest]:
-        """Append one greedy token per request; retire / migrate."""
+        """Append each request's fetched greedy token; retire /
+        migrate."""
         done: List[FinishedRequest] = []
         self._new_first = []
         for st in list(self.in_flight.values()):
             st.prefilled_this_tick = False
-            st.tokens.append(int(np.argmax(st.next_logits)))
+            st.tokens.append(st.next_token)
             if st.first_token_at is None:
                 st.first_token_at = now
                 self._new_first.append(st)
@@ -1153,12 +1163,12 @@ class ContinuousBatchingEngine(_EngineBase):
         slot = self.arenas[new_p].try_alloc()
         if slot is None:
             return
-        logits, cache = self._prefill_running(new_p, st.tokens)
+        token, cache = self._prefill_running(new_p, st.tokens)
         self.arenas[new_p].write_slots(cache, [slot], [len(st.tokens)])
         self.arenas[st.path].free(st.slot)
         st.path, st.slot = new_p, slot
         st.experts = None              # earlier positions ran otherwise
-        st.next_logits = logits
+        st.next_token = token
         st.switches += 1
         st.prefilled_this_tick = True
 

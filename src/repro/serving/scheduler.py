@@ -56,7 +56,7 @@ class RequestState:
     path: int
     slot: int
     tokens: List[int]             # prompt + generated so far
-    next_logits: Optional[np.ndarray] = None  # predicts tokens[len(tokens)]
+    next_token: Optional[int] = None   # greedy tokens[len(tokens)]
     switches: int = 0
     prefilled_this_tick: bool = False
     admitted_at: float = 0.0

@@ -1,8 +1,8 @@
 """The continuous-batching engine's host phases in a ``jax.profiler``
 capture: ``repro.obs`` spans reach the profiler's trace whether the
 engine has no telemetry or a JSONL ``Telemetry``, nested inside each
-``serve.tick``, with the prefill padding and logits-fetch counts the
-benchmark reads."""
+``serve.tick``, with the prefill padding and fetch counts the benchmark
+reads."""
 import glob
 
 import jax
@@ -97,8 +97,9 @@ def test_engine_phases_in_profiler_trace(cfg, paths, tmp_path, sink):
         assert p["tokens"] <= p["padded_tokens"] == \
             p["padded_rows"] * p["bucket"]
         assert p["rows"] <= p["padded_rows"]
-    # a fetch copies the f32 logits of every row its program computed
-    row_bytes = cfg.vocab_size * np.dtype(np.float32).itemsize
+    # a fetch copies the int32 greedy id of every row its program
+    # computed, and nothing else without MoE layers
+    row_bytes = np.dtype(np.int32).itemsize
     for e in inner:
         if e[0] != "serve.fetch":
             continue
